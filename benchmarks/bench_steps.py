@@ -49,6 +49,8 @@ def _summarize_only(tree, ys, r):
 def run(n: int = 20000, perplexity: float = 30.0, theta: float = 0.5):
     fx = tsne_fixture(n, perplexity=perplexity)
     y = fx["y"]
+    # Pallas rows are compiled kernels only on a TPU
+    pallas = "(compiled)" if jax.default_backend() == "tpu" else "(interpret mode)"
 
     # --- BSP (paper: 1.0x single-thread, 17x multithreaded) ---
     # the sequential row loop is timed on a row subsample (it is O(rows) by
@@ -63,7 +65,7 @@ def run(n: int = 20000, perplexity: float = 30.0, theta: float = 0.5):
     )
     emit(f"bsp_naive_rowloop_n{n_bsp}", t_naive, "")
     emit(f"bsp_vectorized_n{n_bsp}", t_opt, f"speedup={t_naive / t_opt:.1f}x")
-    emit(f"bsp_pallas_n{n_bsp}", t_bsp_pl, "(interpret mode)")
+    emit(f"bsp_pallas_n{n_bsp}", t_bsp_pl, pallas)
 
     # --- Quadtree building (paper: 4.5x single-thread, 14.3x multicore) ---
     t_naive = time_fn(lambda: naive.naive_build_and_summarize(y)[0])
@@ -94,7 +96,7 @@ def run(n: int = 20000, perplexity: float = 30.0, theta: float = 0.5):
     emit(f"attractive_blocked_n{n}", t_blk,
          f"speedup={t_naive / t_blk:.1f}x (cache-blocked, default)")
     emit(f"attractive_pallas_n{n}", t_pallas,
-         f"speedup={t_naive / t_pallas:.1f}x (interpret mode)")
+         f"speedup={t_naive / t_pallas:.1f}x {pallas}")
 
     # --- Repulsive (paper: 6.0x single-thread) ---
     summ = _summarize_only(tree, ys, r)
@@ -113,7 +115,7 @@ def run(n: int = 20000, perplexity: float = 30.0, theta: float = 0.5):
     t_xla = time_fn(lambda: morton.morton_encode(y, cent, r))
     t_pl = time_fn(lambda: morton_pallas(y, cent, r))
     emit(f"morton_xla_n{n}", t_xla, "")
-    emit(f"morton_pallas_n{n}", t_pl, "(interpret mode)")
+    emit(f"morton_pallas_n{n}", t_pl, pallas)
 
     # --- FFT-repulsion interpolation spread/gather, xla vs pallas ---
     from repro.core.fft_repulsion import fft_repulsion
@@ -125,4 +127,4 @@ def run(n: int = 20000, perplexity: float = 30.0, theta: float = 0.5):
         iters=2,
     )
     emit(f"fft_interp_xla_n{fft_n}", t_fx, "")
-    emit(f"fft_interp_pallas_n{fft_n}", t_fp, "(interpret mode)")
+    emit(f"fft_interp_pallas_n{fft_n}", t_fp, pallas)

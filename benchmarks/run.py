@@ -77,6 +77,9 @@ def main() -> None:
             f"(known: {', '.join(KNOWN_BENCHES)})"
         )
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     tracer = None
     if args.trace is not None:
         from repro import obs

@@ -21,15 +21,13 @@ O(chunk * K) is ever live.  Nothing in the pipeline materializes anything
 O(N^2).  Pass --neighbor_method exact to get the brute-force scan back for
 oracle comparisons at small --n.
 
-``--shards S`` forces S host devices (the flag is translated to
-``XLA_FLAGS=--xla_force_host_platform_device_count=S`` before jax loads;
-on real multi-device hardware the visible devices are used as-is).
+``--shards S`` rings the KNN over S of the visible devices (0 = all of
+them); it fails when fewer than S are visible.
 Checkpointing (--ckpt_dir) makes multi-hour full-size runs restartable;
 ``--bench-out DIR`` records the run as the next ``BENCH_<n>.json`` artifact
 with the per-phase breakdown (docs/BENCHMARKS.md schema).
 """
 import argparse
-import os
 import pathlib
 import sys
 import time
@@ -51,7 +49,7 @@ def parse_args():
                     help="KNN degree (default: 3 * perplexity)")
     ap.add_argument("--shards", type=int, default=0,
                     help="device shards for the sharded KNN ring (0 = all "
-                         "visible devices; >1 forces that many host devices)")
+                         "visible devices)")
     ap.add_argument("--chunk-size", type=int, default=0,
                     help="rows per BSP/symmetrize slice (0 = auto: 100k "
                          "chunks above 200k points, unchunked below)")
@@ -71,18 +69,15 @@ AUTO_CHUNK = 100_000
 
 def main():
     args = parse_args()
-    if args.shards > 1 and "XLA_FLAGS" not in os.environ:
-        # must land before jax initializes its backends
-        os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.shards}"
-        )
-
     import jax.numpy as jnp
     import numpy as np
 
     from repro.api import make_backend
+    from repro.compile_cache import enable_compile_cache
     from repro.core.tsne import TsneConfig, init_state, preprocess, tsne_step
     from repro.data.datasets import make_dataset
+
+    enable_compile_cache()
 
     neighbor_method = args.neighbor_method
     if neighbor_method == "auto":

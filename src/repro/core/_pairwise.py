@@ -2,6 +2,7 @@
 pairwise kernel; also its correctness oracle)."""
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 
 
@@ -11,5 +12,7 @@ def pairwise_sq_dists(q, db, q_sqn=None, db_sqn=None):
         q_sqn = jnp.sum(q * q, axis=1)
     if db_sqn is None:
         db_sqn = jnp.sum(db * db, axis=1)
-    dots = q @ db.T
+    # full f32: a TPU's default one-pass bf16 product blurs the gaps
+    # between neighbor ranks
+    dots = jnp.matmul(q, db.T, precision=jax.lax.Precision.HIGHEST)
     return q_sqn[:, None] + db_sqn[None, :] - 2.0 * dots

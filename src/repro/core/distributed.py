@@ -22,6 +22,9 @@ Two KNN rings live here:
   Per-hop compute is O(n_loc · T·leaf · D) — the N²/S distance tile is
   gone — and every merge is row-blocked (``block_rows``), so peak memory
   is bounded by the block size, not the shard size.
+
+:func:`refine_knn` then polishes the approximate ring's graph with
+NN-descent rounds across shards.
 """
 from __future__ import annotations
 
@@ -29,7 +32,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from repro.compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import attractive, morton, quadtree
@@ -277,3 +280,86 @@ def ring_knn_approx(
 
     return shard_map(body, mesh=mesh, in_specs=P(axis),
                      out_specs=(P(axis), P(axis)), check_vma=False)(x)
+
+
+def refine_knn(
+    mesh, x, idx, d2, k: int, axis: str = "data", *,
+    n_valid: int | None = None,
+    n_iters: int = 5,
+    n_sample: int = 24,
+    n_reverse: int = 24,
+    block_rows: int = 4096,
+    seed: int = 0,
+):
+    """NN-descent rounds over a row-sharded KNN graph (``nn_descent_knn``'s
+    rounds, across shards).
+
+    x [N, D], idx/d2 [N, k] (global indices) sharded row-wise over ``axis``;
+    rows >= ``n_valid`` are padding and never emitted.  Each round every
+    shard all-gathers the graph, takes ``n_sample`` sampled neighbors' sampled
+    neighbors as forward candidates plus a bounded sample of reverse edges
+    (nominated by every shard, combined with ``pmax``), scores them against
+    the all-gathered x and merges them into its rows' top-k.  The ring's
+    candidates come from one shard's forest at a time, so its recall falls
+    as shards grow; a neighbor of a neighbor may live on any shard.
+
+    Resident per shard: x and the graph in full (``N * (D + k)`` words);
+    transients are row-blocked by ``block_rows``.  A round scores
+    ``n_sample² + n_reverse`` candidates per row; the defaults take the
+    1,291,337 x 20 mouse stand-in over 4 shards from recall@90 0.58 after
+    the 8-tree ring to 0.94.
+    """
+    from repro.neighbors._candidates import candidate_sq_dists, merge_topk
+
+    n_dev = mesh.shape[axis]
+    n_pad_total = x.shape[0]
+    n_total = n_pad_total if n_valid is None else int(n_valid)
+    n_loc = n_pad_total // n_dev
+    s = min(n_sample, k)
+    block = min(block_rows, n_loc)
+    m_pad = -(-n_loc // block) * block
+
+    def body(x_loc, i_loc, d_loc):
+        rank = jax.lax.axis_index(axis)
+        base = rank * n_loc
+        big = jnp.asarray(jnp.finfo(x_loc.dtype).max, x_loc.dtype)
+        x_full = jax.lax.all_gather(x_loc, axis, tiled=True)
+        gid = base + jnp.arange(n_loc, dtype=jnp.int32)
+        key = jax.random.fold_in(jax.random.PRNGKey(seed), rank)
+        pad = lambda a, v: jnp.pad(a, ((0, m_pad - n_loc),) + ((0, 0),) * (a.ndim - 1),
+                                   constant_values=v)
+        blk = lambda a: a.reshape(m_pad // block, block, *a.shape[1:])
+        x_blk, gid_blk = blk(pad(x_loc, 0.0)), blk(pad(gid, -1))
+
+        def one_round(it, carry):
+            bi, bd = carry
+            i_full = jax.lax.all_gather(bi, axis, tiled=True)      # [N, k]
+            k1, k2, k3 = jax.random.split(jax.random.fold_in(key, it), 3)
+            samp = jnp.take_along_axis(
+                bi, jax.random.randint(k1, (n_loc, s), 0, k), axis=1)
+            samp = jnp.clip(samp, 0, n_pad_total - 1)
+            hop2 = jax.random.randint(k2, (n_loc, s), 0, k)
+            # edge i -> samp[i, j] nominates i as a candidate of samp[i, j],
+            # wherever that row lives; slot collisions just drop
+            slots = jax.random.randint(k3, (n_loc, s), 0, n_reverse)
+            rev = jnp.full((n_pad_total, n_reverse), -1, jnp.int32).at[
+                samp, slots].set(jnp.broadcast_to(gid[:, None], (n_loc, s)))
+            rev = jax.lax.dynamic_slice_in_dim(jax.lax.pmax(rev, axis), base, n_loc)
+
+            def merge_block(args):
+                qb, gb, sb, hb, rb, bib, bdb = args
+                fwd = i_full[sb[:, :, None], hb[:, None, :]].reshape(-1, s * s)
+                cb = jnp.concatenate([fwd, rb], axis=1)
+                cd = candidate_sq_dists(x_full, cb, block_rows=block, q=qb)
+                cd = jnp.where(cb == gb[:, None], big, cd)       # self edge
+                return merge_topk(bib, bdb, cb, cd, k, n_total, exclude_self=False)
+
+            mi, md = jax.lax.map(merge_block, (
+                x_blk, gid_blk, blk(pad(samp, 0)), blk(pad(hop2, 0)),
+                blk(pad(rev, -1)), blk(pad(bi, -1)), blk(pad(bd, big))))
+            return mi.reshape(m_pad, k)[:n_loc], md.reshape(m_pad, k)[:n_loc]
+
+        return jax.lax.fori_loop(0, n_iters, one_round, (i_loc, d_loc))
+
+    return shard_map(body, mesh=mesh, in_specs=(P(axis), P(axis), P(axis)),
+                     out_specs=(P(axis), P(axis)), check_vma=False)(x, idx, d2)

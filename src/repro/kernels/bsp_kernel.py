@@ -27,6 +27,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 TILE = 256
 
 
@@ -67,7 +69,7 @@ def _bsp_kernel(d2_ref, par_ref, cond_ref, beta_ref, *, iters: int):
     beta, _, _ = jax.lax.fori_loop(0, iters, body, state)
     _, p, sum_p = entropy(beta)
     cond_ref[...] = p / sum_p
-    beta_ref[...] = (beta / scale)[:, 0]
+    beta_ref[...] = beta / scale
 
 
 @functools.partial(jax.jit, static_argnames=("iters", "interpret"))
@@ -76,7 +78,7 @@ def binary_search_perplexity_pallas(
     perplexity,
     iters: int = 64,
     tol: float = 1e-5,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Fused per-tile bisection; same contract as the ``core/bsp`` reference.
 
@@ -101,12 +103,12 @@ def binary_search_perplexity_pallas(
         ],
         out_specs=[
             pl.BlockSpec((TILE, k), lambda i: (i, 0)),
-            pl.BlockSpec((TILE,), lambda i: (i,)),
+            pl.BlockSpec((TILE, 1), lambda i: (i, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((n_pad, k), dtype),
-            jax.ShapeDtypeStruct((n_pad,), dtype),
+            jax.ShapeDtypeStruct((n_pad, 1), dtype),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(d2p, par)
-    return cond_p[:n], beta[:n]
+    return cond_p[:n], beta[:n, 0]

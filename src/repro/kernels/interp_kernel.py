@@ -35,6 +35,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 P_ORDER = 3      # must match core/fft_repulsion.P_ORDER
 TILE = 256
 LANE = 128       # node-lattice padding boundary
@@ -59,7 +61,8 @@ def _spread_kernel(base_ref, wx_ref, wy_ref, ch_ref, out_ref, *, n_ch: int):
     acc = jnp.stack([
         jax.lax.dot_general(
             w_x * ch[:, c][:, None], w_y,
-            (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+            (((0,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+            preferred_element_type=jnp.float32,
         ).astype(out_ref.dtype)
         for c in range(n_ch)
     ])                                   # [C, G, G]
@@ -83,7 +86,8 @@ def _gather_kernel(pot_ref, base_ref, wx_ref, wy_ref, out_ref, *, n_ch: int):
         jnp.sum(
             jax.lax.dot_general(
                 w_x, pot[c],
-                (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+                (((1,), (0,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32,
             ).astype(out_ref.dtype) * w_y,
             axis=1,
         )
@@ -105,7 +109,7 @@ def _pad_points(base, wx, wy, extra=None):
 
 
 @functools.partial(jax.jit, static_argnames=("nodes", "interpret"))
-def spread_to_grid_pallas(base, wx, wy, charges, nodes: int, interpret: bool = True):
+def spread_to_grid_pallas(base, wx, wy, charges, nodes: int, interpret: bool | None = None):
     """Same contract as ``core/fft_repulsion.spread_to_grid``."""
     c = charges.shape[1]
     g = (nodes + LANE - 1) // LANE * LANE
@@ -121,13 +125,13 @@ def spread_to_grid_pallas(base, wx, wy, charges, nodes: int, interpret: bool = T
         ],
         out_specs=pl.BlockSpec((c, g, g), lambda i: (0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((c, g, g), charges.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(basep, wxp, wyp, chp)
     return jnp.transpose(grid, (1, 2, 0))[:nodes, :nodes, :]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def gather_from_grid_pallas(pot, base, wx, wy, interpret: bool = True):
+def gather_from_grid_pallas(pot, base, wx, wy, interpret: bool | None = None):
     """Same contract as ``core/fft_repulsion.gather_from_grid``."""
     nodes, _, c = pot.shape
     n = base.shape[0]
@@ -146,6 +150,6 @@ def gather_from_grid_pallas(pot, base, wx, wy, interpret: bool = True):
         ],
         out_specs=pl.BlockSpec((TILE, c), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, c), pot.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(potp, basep, wxp, wyp)
     return phi[:n]

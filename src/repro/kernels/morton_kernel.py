@@ -14,6 +14,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 TILE = 1024
 
 
@@ -32,10 +34,12 @@ def _morton_kernel(y_ref, root_ref, out_ref, *, depth: int):
     y_root_y = root_ref[0, 1]
     scale = root_ref[0, 2]
     hi = jnp.asarray(float(2**depth) - 1.0, y.dtype)
-    mx_f = jnp.clip((y[:, 0] - y_root_x) * scale, 0.0, hi)
-    my_f = jnp.clip((y[:, 1] - y_root_y) * scale, 0.0, hi)
-    mx = _expand_bits(mx_f.astype(jnp.uint32))
-    my = _expand_bits(my_f.astype(jnp.uint32))
+    mx_f = jnp.clip((y[:, 0:1] - y_root_x) * scale, 0.0, hi)    # [TILE, 1]
+    my_f = jnp.clip((y[:, 1:2] - y_root_y) * scale, 0.0, hi)
+    # float -> int32 -> uint32: Mosaic has no direct float -> uint32 cast;
+    # the clipped values are in [0, 2^16) so both steps are exact
+    mx = _expand_bits(jax.lax.bitcast_convert_type(mx_f.astype(jnp.int32), jnp.uint32))
+    my = _expand_bits(jax.lax.bitcast_convert_type(my_f.astype(jnp.int32), jnp.uint32))
     code = mx | (my << 1)
     if depth < 16:
         code = code & jnp.uint32((1 << (2 * depth)) - 1)
@@ -43,7 +47,7 @@ def _morton_kernel(y_ref, root_ref, out_ref, *, depth: int):
 
 
 @functools.partial(jax.jit, static_argnames=("depth", "interpret"))
-def morton_encode_pallas(y, cent, r_span, depth: int = 16, interpret: bool = True):
+def morton_encode_pallas(y, cent, r_span, depth: int = 16, interpret: bool | None = None):
     n = y.shape[0]
     n_pad = (n + TILE - 1) // TILE * TILE
     yp = jnp.pad(y, ((0, n_pad - n), (0, 0)))
@@ -57,8 +61,8 @@ def morton_encode_pallas(y, cent, r_span, depth: int = 16, interpret: bool = Tru
             pl.BlockSpec((TILE, 2), lambda i: (i, 0)),
             pl.BlockSpec((1, 4), lambda i: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((TILE,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n_pad,), jnp.uint32),
-        interpret=interpret,
+        out_specs=pl.BlockSpec((TILE, 1), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((n_pad, 1), jnp.uint32),
+        interpret=interpret_mode(interpret),
     )(yp, root)
-    return out[:n]
+    return out[:n, 0]

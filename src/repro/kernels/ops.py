@@ -1,8 +1,10 @@
-"""Jitted public wrappers around the Pallas kernels + the kernel registry.
+"""Public names of the Pallas kernels + the kernel registry.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernels TARGET TPU v5e and are validated via the interpreter against the
-pure-jnp oracles in ref.py).
+Each name is the kernel's own jitted entry point; whether it compiles or
+runs in the Pallas interpreter is decided when it is traced
+(``repro.kernels.interpret_mode``): compiled on a TPU, interpreted off it
+only where asked for.  The kernels target TPU v5e and are checked against
+the pure-jnp oracles in ref.py.
 
 ``kernel_registry()`` / ``get_kernel()`` form the registry-style dispatch
 table the core modules and the roofline tool share: one entry per
@@ -15,48 +17,24 @@ which are still plain XLA.  See docs/KERNELS.md for the playbook.
 """
 from __future__ import annotations
 
-import jax
-
-from repro.kernels.attractive_kernel import attractive_forces_ell_pallas
-from repro.kernels.bsp_kernel import binary_search_perplexity_pallas
-from repro.kernels.interp_kernel import (
-    gather_from_grid_pallas, spread_to_grid_pallas,
+from repro.kernels.attractive_kernel import (
+    attractive_forces_ell_pallas as attractive_forces_ell,
 )
-from repro.kernels.morton_kernel import morton_encode_pallas
-from repro.kernels.pairwise_kernel import pairwise_sq_dists_pallas
-
-_INTERPRET = jax.default_backend() != "tpu"
-
-
-def morton_encode(y, cent, r_span, depth: int = 16):
-    return morton_encode_pallas(y, cent, r_span, depth=depth, interpret=_INTERPRET)
-
-
-def pairwise_sq_dists(q, db, q_sqn=None, db_sqn=None):
-    return pairwise_sq_dists_pallas(q, db, q_sqn, db_sqn, interpret=_INTERPRET)
-
-
-def attractive_forces_ell(y, cols, vals):
-    return attractive_forces_ell_pallas(y, cols, vals, interpret=_INTERPRET)
-
-
-def binary_search_perplexity(d2, perplexity, iters: int = 64, tol: float = 1e-5):
-    return binary_search_perplexity_pallas(
-        d2, perplexity, iters=iters, tol=tol, interpret=_INTERPRET
-    )
-
-
-def fft_spread(base, wx, wy, charges, nodes: int):
-    return spread_to_grid_pallas(base, wx, wy, charges, nodes,
-                                 interpret=_INTERPRET)
-
-
-def fft_gather(pot, base, wx, wy):
-    return gather_from_grid_pallas(pot, base, wx, wy, interpret=_INTERPRET)
+from repro.kernels.bsp_kernel import (
+    binary_search_perplexity_pallas as binary_search_perplexity,
+)
+from repro.kernels.interp_kernel import (
+    gather_from_grid_pallas as fft_gather,
+    spread_to_grid_pallas as fft_spread,
+)
+from repro.kernels.morton_kernel import morton_encode_pallas as morton_encode
+from repro.kernels.pairwise_kernel import (
+    pairwise_sq_dists_pallas as pairwise_sq_dists,
+)
 
 
 def kernel_registry() -> dict:
-    """name -> dict(ref=oracle fn, pallas=interpret-aware wrapper, doc).
+    """name -> dict(ref=oracle fn, pallas=kernel entry point, doc).
 
     Built lazily: the oracles live in ``repro.core`` which must not import
     at ``repro.kernels`` import time (core modules lazily import this module

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import interpret_mode
+
 TQ = 128
 TC = 128
 
@@ -22,15 +24,18 @@ TC = 128
 def _pairwise_kernel(q_ref, c_ref, qn_ref, cn_ref, out_ref):
     q = q_ref[...]                       # [TQ, D]
     c = c_ref[...]                       # [TC, D]
+    # full f32: the TPU default is one bf16 pass, too coarse to rank
+    # neighbors
     dots = jax.lax.dot_general(
-        q, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        q, c, (((1,), (1,)), ((), ())), precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32,
     )                                    # [TQ, TC] on the MXU
-    out = qn_ref[...].reshape(-1, 1) + cn_ref[...].reshape(1, -1) - 2.0 * dots
+    out = qn_ref[...] + cn_ref[...] - 2.0 * dots        # [TQ,1] + [1,TC]
     out_ref[...] = jnp.maximum(out, 0.0).astype(out_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def pairwise_sq_dists_pallas(q, db, q_sqn=None, db_sqn=None, interpret: bool = True):
+def pairwise_sq_dists_pallas(q, db, q_sqn=None, db_sqn=None, interpret: bool | None = None):
     nq, d = q.shape
     nc = db.shape[0]
     if q_sqn is None:
@@ -41,19 +46,19 @@ def pairwise_sq_dists_pallas(q, db, q_sqn=None, db_sqn=None, interpret: bool = T
     nc_pad = (nc + TC - 1) // TC * TC
     qp = jnp.pad(q, ((0, nq_pad - nq), (0, 0)))
     cp = jnp.pad(db, ((0, nc_pad - nc), (0, 0)))
-    qnp_ = jnp.pad(q_sqn, (0, nq_pad - nq))
-    cnp_ = jnp.pad(db_sqn, (0, nc_pad - nc))
+    qnp_ = jnp.pad(q_sqn, (0, nq_pad - nq))[:, None]     # [nq_pad, 1]
+    cnp_ = jnp.pad(db_sqn, (0, nc_pad - nc))[None, :]     # [1, nc_pad]
     out = pl.pallas_call(
         _pairwise_kernel,
         grid=(nq_pad // TQ, nc_pad // TC),
         in_specs=[
             pl.BlockSpec((TQ, d), lambda i, j: (i, 0)),
             pl.BlockSpec((TC, d), lambda i, j: (j, 0)),
-            pl.BlockSpec((TQ,), lambda i, j: (i,)),
-            pl.BlockSpec((TC,), lambda i, j: (j,)),
+            pl.BlockSpec((TQ, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, TC), lambda i, j: (0, j)),
         ],
         out_specs=pl.BlockSpec((TQ, TC), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((nq_pad, nc_pad), q.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(qp, cp, qnp_, cnp_)
     return out[:nq, :nc]

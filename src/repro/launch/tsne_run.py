@@ -2,12 +2,16 @@
 
     PYTHONPATH=src python -m repro.launch.tsne_run --dataset digits --n 1797
     PYTHONPATH=src python -m repro.launch.tsne_run --method fft --n 4096
-    XLA_FLAGS=--xla_force_host_platform_device_count=8 \
-      PYTHONPATH=src python -m repro.launch.tsne_run --dataset mnist --n 4096 --devices 8
+    PYTHONPATH=src python -m repro.launch.tsne_run --dataset mnist --n 4096 --devices 4
+
+``--devices`` needs that many visible devices (the four chips of a v5e
+2x2 host, or virtual CPU devices that a rehearsal sets up itself with
+``XLA_FLAGS=--xla_force_host_platform_device_count=4``).
 """
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import functools
 
 import jax
@@ -15,16 +19,16 @@ import jax
 
 @functools.partial(jax.jit, static_argnames=(
     "mesh", "theta", "exag", "mom", "lr", "min_gain"))
-def _dist_step(state, cols, vals, *, mesh, theta, exag, mom, lr, min_gain):
+def _dist_step(state, graph, *, mesh, theta, exag, mom, lr, min_gain):
     """One sharded GD step.  Module-level so the compile cache is shared
-    across iterations; ``cols``/``vals`` are operands (not closure
+    across iterations; the graph's arrays are operands (not closure
     captures baked into the jaxpr as constants).  (exag, mom) take two
     values each over a run — at most 4 traces."""
     from repro.core.distributed import distributed_bh_gradient
     from repro.core.tsne import gd_update
 
-    res = distributed_bh_gradient(mesh, state.y, cols, vals, 0.0,
-                                  theta=theta, exaggeration=exag)
+    res = distributed_bh_gradient(mesh, state.y, graph.p_cols, graph.p_vals,
+                                  graph.p_logp, theta=theta, exaggeration=exag)
     return gd_update(state, res.grad, lr, mom, min_gain), res.kl
 
 
@@ -45,12 +49,11 @@ def main():
     import jax.numpy as jnp
     import numpy as np
     from repro.api import TSNE
-    from repro.core import bsp
-    from repro.core.knn import knn
-    from repro.core.similarity import symmetrize_ell
-    from repro.core.tsne import TsneConfig, init_state
+    from repro.compile_cache import enable_compile_cache
+    from repro.core.tsne import TsneConfig, init_state, preprocess
     from repro.data.datasets import make_dataset
 
+    enable_compile_cache()
     x, _ = make_dataset(args.dataset, n=args.n)
     cfg = TsneConfig(perplexity=args.perplexity, theta=args.theta, n_iter=args.iters)
 
@@ -62,24 +65,20 @@ def main():
         print(f"KL={est.kl_divergence_:.4f} n_iter={est.n_iter_} -> {args.out}")
         return
 
-    # distributed path: points sharded over a 1-D data mesh
-    from repro.core.distributed import ring_knn
+    # distributed path: points sharded over a 1-D data mesh, the graph
+    # built by the exact KNN ring over the same devices
     mesh = jax.make_mesh((args.devices,), ("data",))
     n = args.n - args.n % args.devices
-    x = jnp.asarray(x[:n])
-    k = cfg.resolve_n_neighbors(n)
-    idx, d2 = ring_knn(mesh, x, k)
-    cond_p, _ = bsp.binary_search_perplexity(d2, cfg.perplexity)
-    cols, vals = symmetrize_ell(np.asarray(idx), np.asarray(cond_p))
-    cols = jnp.asarray(cols)
-    vals = jnp.asarray(vals, jnp.float32)
+    graph, _ = preprocess(jnp.asarray(x[:n]), dataclasses.replace(
+        cfg, neighbor_method="sharded", neighbor_options={"mode": "exact"},
+        knn_shards=args.devices))
     state = init_state(n, cfg)
     lr = cfg.resolve_lr(n)
 
     for it in range(args.iters):
         exag = cfg.early_exaggeration if it < cfg.exaggeration_iters else 1.0
         mom = cfg.momentum_initial if it < cfg.momentum_switch_iter else cfg.momentum_final
-        state, kl = _dist_step(state, cols, vals, mesh=mesh, theta=cfg.theta,
+        state, kl = _dist_step(state, graph, mesh=mesh, theta=cfg.theta,
                                exag=exag, mom=mom, lr=lr,
                                min_gain=cfg.min_gain)
         if (it + 1) % 100 == 0:

@@ -21,7 +21,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 
 from repro.configs.base import ArchConfig
 from repro.distributed.sharding import active_rules, logical

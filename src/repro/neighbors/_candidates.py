@@ -85,7 +85,7 @@ def candidate_sq_dists(
         xb = lax.dynamic_slice_in_dim(xp, b * block_rows, block_rows)
         cb = lax.dynamic_slice_in_dim(candp, b * block_rows, block_rows)
         xc = x[cb]                                   # [B, C, D]
-        dots = jnp.einsum("bd,bcd->bc", xb, xc)
+        dots = jnp.einsum("bd,bcd->bc", xb, xc, precision=lax.Precision.HIGHEST)
         d2 = jnp.sum(xb * xb, axis=1)[:, None] + sqn[cb] - 2.0 * dots
         return jnp.maximum(d2, 0.0)
 

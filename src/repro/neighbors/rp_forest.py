@@ -52,7 +52,8 @@ def _build_tree(
     """
     n, d = x.shape
     dirs = jax.random.normal(key, (depth, d), x.dtype)
-    proj = x @ dirs.T if depth else None             # [N, depth]
+    # full f32 (as in route_to_leaves): a query routes like a member point
+    proj = jnp.matmul(x, dirs.T, precision=lax.Precision.HIGHEST) if depth else None
     order = jnp.arange(n_pad, dtype=jnp.int32)
     big = jnp.asarray(jnp.finfo(x.dtype).max, x.dtype)
     pos = jnp.arange(n_pad, dtype=jnp.int32)
@@ -82,7 +83,7 @@ def _leaf_topk(x: jax.Array, leaves: jax.Array, k: int, n_pad: int):
     xb = x[safe]                                     # [L, S, D]
     sqn = jnp.sum(xb * xb, axis=2)
     d2 = sqn[:, :, None] + sqn[:, None, :] - 2.0 * jnp.einsum(
-        "lsd,ltd->lst", xb, xb
+        "lsd,ltd->lst", xb, xb, precision=lax.Precision.HIGHEST
     )
     big = jnp.asarray(jnp.finfo(x.dtype).max, x.dtype)
     pad_col = (leaves >= n)[:, None, :]
@@ -147,17 +148,11 @@ def build_forest_index(
     points were bucketed by.
     """
     key = jax.random.PRNGKey(seed)
-    leaves, dirs, thrs = [], [], []
-    for t in range(n_trees):
-        lv, dr, th = _build_tree(x, jax.random.fold_in(key, t), depth, n_pad)
-        leaves.append(lv)
-        dirs.append(dr)
-        thrs.append(th)
-    return (
-        jnp.stack(leaves),
-        jnp.stack(dirs),
-        tuple(jnp.stack([th[l] for th in thrs]) for l in range(depth)),
-    )
+    keys = jax.vmap(functools.partial(jax.random.fold_in, key))(
+        jnp.arange(n_trees))
+    # one batched sort per level for all trees, not n_trees unrolled ones:
+    # the TPU compile time grows with the number of large sorts
+    return jax.vmap(lambda k: _build_tree(x, k, depth, n_pad))(keys)
 
 
 def route_to_leaves(
@@ -180,7 +175,8 @@ def route_to_leaves(
     tree_ids = jnp.arange(n_trees, dtype=jnp.int32)[None, :]      # [1, T]
     node = jnp.zeros((m, n_trees), jnp.int32)
     if depth:
-        proj = jnp.einsum("md,tld->mtl", q, dirs)                 # [M, T, depth]
+        proj = jnp.einsum("md,tld->mtl", q, dirs,                 # [M, T, depth]
+                          precision=lax.Precision.HIGHEST)
         for level in range(depth):
             thr = thrs[level][tree_ids, node]                     # [M, T]
             node = node * 2 + (proj[:, :, level] > thr).astype(jnp.int32)

@@ -9,17 +9,18 @@ brute-force KNN — O(N²/S · D) per shard, the reason nothing had run past
 * ``mode="approx"`` (default) — per-shard rp_forest + candidate ring
   (:func:`repro.core.distributed.ring_knn_approx`): each shard routes the
   visiting query block down its resident forest and merges leaf candidates
-  into the traveling global top-k.  Peak memory is bounded by
-  ``block_rows``, not N.
+  into the traveling global top-k; then ``refine_iters`` neighbor-of-
+  neighbor rounds across shards (:func:`repro.core.distributed.refine_knn`)
+  find what one shard's forest at a time could not.  Transients are
+  bounded by ``block_rows``, not N.
 * ``mode="exact"`` — the original exact ring
   (:func:`repro.core.distributed.ring_knn`), kept as the recall oracle.
 
-``shards=None`` uses every visible JAX device (1 on a plain CPU process —
-the ring degenerates to a single local forest pass, still row-blocked, so
-the memory bound holds on one device too).  Force S host devices with
-``XLA_FLAGS=--xla_force_host_platform_device_count=S`` before importing
-jax.  Inputs of any N are handled by zero-padding to a shard multiple;
-pad rows are masked out of every merge.
+``shards=None`` uses every visible JAX device: the four chips of a
+TPU v5e 2x2 host, say.  On one device the ring degenerates to a single
+local forest pass, still row-blocked, so the memory bound holds there too.
+Inputs of any N are handled by zero-padding to a shard multiple; pad rows
+are masked out of every merge.
 """
 from __future__ import annotations
 
@@ -46,6 +47,9 @@ class ShardedNeighbors:
     n_trees    : forest width per shard (approx mode)
     leaf_size  : leaf occupancy floor per tree (approx mode); the candidate
                  set per hop is n_trees * max(leaf_size, k+1)-ish columns
+    refine_iters : NN-descent rounds after the ring (approx mode).  On the
+                 1,291,337 x 20 mouse stand-in over 4 shards the ring alone
+                 gives recall@90 0.58; 5 rounds bring it to 0.94
     block_rows : rows per routing/scoring/merge slice — the memory knob
     """
 
@@ -54,6 +58,7 @@ class ShardedNeighbors:
     mode: str = "approx"
     n_trees: int = 8
     leaf_size: int = 64
+    refine_iters: int = 5
     block_rows: int = 4096
     seed: int = 0
     axis: str = "knn"
@@ -71,14 +76,14 @@ class ShardedNeighbors:
         s = avail if self.shards in (None, 0) else int(self.shards)
         if s > avail:
             raise ValueError(
-                f"shards={s} but only {avail} JAX device(s) are visible — "
-                "set XLA_FLAGS=--xla_force_host_platform_device_count="
-                f"{s} before importing jax, or lower shards"
+                f"shards={s} but only {avail} {jax.default_backend()} "
+                "device(s) are visible — lower shards or run on a host "
+                f"with {s} devices"
             )
         return max(1, min(s, n // (k + 2)))
 
     def neighbors(self, x: jax.Array, k: int) -> tuple[jax.Array, jax.Array]:
-        from repro.core.distributed import ring_knn, ring_knn_approx
+        from repro.core.distributed import refine_knn, ring_knn, ring_knn_approx
 
         n = int(x.shape[0])
         validate_k(n, k)
@@ -94,6 +99,13 @@ class ShardedNeighbors:
                 n_trees=self.n_trees, leaf_size=self.leaf_size,
                 block_rows=self.block_rows, seed=self.seed,
             )
+            if self.refine_iters > 0:
+                # offset the seed: the rounds must not replay the forest's keys
+                idx, d2 = refine_knn(
+                    mesh, xp, idx, d2, k, self.axis, n_valid=n,
+                    n_iters=self.refine_iters, block_rows=self.block_rows,
+                    seed=self.seed + 1,
+                )
         return idx[:n], d2[:n]
 
 

@@ -201,3 +201,28 @@ def test_tsne_fft_backend_with_pallas_interp_runs():
     res = run_tsne(x, cfg, kl_every=16)
     assert np.isfinite(res.y).all() and np.isfinite(res.kl)
     assert res.timings["bsp_impl"] == "pallas"
+
+
+@pytest.mark.parametrize("backend,env,asked,expected", [
+    ("tpu", "1", None, False),      # on a TPU the kernels always compile
+    ("cpu", "1", None, True),       # the CPU suite asks for the interpreter
+    ("cpu", None, None, False),     # nobody asked: request the compiled kernel
+    ("cpu", None, True, True),
+    ("cpu", "1", False, False),
+])
+def test_interpret_mode_is_decided_at_call_time(monkeypatch, backend, env,
+                                                asked, expected):
+    from repro.kernels import INTERPRET_ENV, interpret_mode
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if env is None:
+        monkeypatch.delenv(INTERPRET_ENV, raising=False)
+    else:
+        monkeypatch.setenv(INTERPRET_ENV, env)
+    assert interpret_mode(asked) is expected
+
+
+def test_interpret_mode_is_refused_on_tpu(monkeypatch):
+    from repro.kernels import interpret_mode
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="never used on a TPU"):
+        interpret_mode(True)
