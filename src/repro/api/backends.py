@@ -24,7 +24,7 @@ from typing import Callable, ClassVar, Protocol, runtime_checkable
 import jax
 import jax.numpy as jnp
 
-from repro.core import attractive, exact
+from repro.core import attractive, exact, scopes
 from repro.core.fft_repulsion import fft_repulsion
 from repro.core.tsne import (
     DEFAULT_ATTRACTIVE_IMPL, GradResult, NeighborGraph, TsneConfig, bh_gradient,
@@ -54,24 +54,27 @@ class GradientBackend(Protocol):
 
 def _attractive(y, graph: NeighborGraph, attractive_impl: str,
                 attractive_block: int = 512):
-    if attractive_impl == "edges":
-        if not graph.has_edges:
+    with jax.named_scope(scopes.ATTRACTIVE):
+        if attractive_impl == "edges":
+            if not graph.has_edges:
+                raise ValueError(
+                    "attractive_impl='edges' but the NeighborGraph carries no "
+                    "edge list — preprocess with "
+                    "TsneConfig(attractive_impl='edges')"
+                )
+            return attractive.attractive_forces_edges(y, *graph.edges)
+        if graph.p_cols.shape[0] != y.shape[0]:
             raise ValueError(
-                "attractive_impl='edges' but the NeighborGraph carries no edge "
-                "list — preprocess with TsneConfig(attractive_impl='edges')"
+                f"attractive_impl={attractive_impl!r} needs the ELL rows, but "
+                "this NeighborGraph was preprocessed edges-only "
+                "(attractive_impl='edges')"
             )
-        return attractive.attractive_forces_edges(y, *graph.edges)
-    if graph.p_cols.shape[0] != y.shape[0]:
-        raise ValueError(
-            f"attractive_impl={attractive_impl!r} needs the ELL rows, but this "
-            "NeighborGraph was preprocessed edges-only "
-            "(attractive_impl='edges')"
-        )
-    if attractive_impl == "blocked":
-        return attractive.attractive_forces_ell_blocked(
-            y, graph.p_cols, graph.p_vals, block=attractive_block
-        )
-    return attractive.ell_impl(attractive_impl)(y, graph.p_cols, graph.p_vals)
+        if attractive_impl == "blocked":
+            return attractive.attractive_forces_ell_blocked(
+                y, graph.p_cols, graph.p_vals, block=attractive_block
+            )
+        return attractive.ell_impl(attractive_impl)(y, graph.p_cols,
+                                                    graph.p_vals)
 
 
 # --------------------------------------------------------------------------

@@ -26,6 +26,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.core import scopes
+
 P_ORDER = 3  # interpolation nodes per box per dim (cubic-ish accuracy)
 
 # Hard cap on the boxes-per-dim grid resolution.  The Pallas interp kernels
@@ -129,32 +131,36 @@ def fft_repulsion(y: jax.Array, n_boxes: int = 48, interp_impl: str = "xla"):
     dtype = y.dtype
     m = n_boxes * (P_ORDER - 1)
     nodes = m + 1
-    base, wx, wy, h = interp_coords(y, n_boxes)
-
-    # spread charges {1, yx, yy} onto the (m+1)^2 node lattice
-    charges = jnp.stack([jnp.ones((n,), dtype), y[:, 0], y[:, 1]], axis=1)
-    grid = spread(base, wx, wy, charges, nodes)            # [nodes, nodes, 3]
+    with jax.named_scope(scopes.FFT_SPREAD):
+        base, wx, wy, h = interp_coords(y, n_boxes)
+        # spread charges {1, yx, yy} onto the (m+1)^2 node lattice
+        charges = jnp.stack([jnp.ones((n,), dtype), y[:, 0], y[:, 1]], axis=1)
+        grid = spread(base, wx, wy, charges, nodes)        # [nodes, nodes, 3]
 
     # kernel convolution via circulant embedding (size 2*nodes)
-    big = 2 * nodes
-    dx = jnp.minimum(jnp.arange(big), big - jnp.arange(big)).astype(dtype) * h
-    d2 = dx[:, None] ** 2 + dx[None, :] ** 2
-    k1 = 1.0 / (1.0 + d2)
-    k2 = k1 * k1
-    fk1 = jnp.fft.rfft2(k1)
-    fk2 = jnp.fft.rfft2(k2)
-    gpad = jnp.pad(grid, ((0, big - nodes), (0, big - nodes), (0, 0)))
-    fg = jnp.fft.rfft2(gpad, axes=(0, 1))
-    pot2 = jnp.fft.irfft2(fg * fk2[:, :, None], s=(big, big), axes=(0, 1))[:nodes, :nodes]
-    pot1 = jnp.fft.irfft2(fg[..., 0] * fk1, s=(big, big))[:nodes, :nodes]
+    with jax.named_scope(scopes.FFT_CONVOLVE):
+        big = 2 * nodes
+        dx = jnp.minimum(jnp.arange(big),
+                         big - jnp.arange(big)).astype(dtype) * h
+        d2 = dx[:, None] ** 2 + dx[None, :] ** 2
+        k1 = 1.0 / (1.0 + d2)
+        k2 = k1 * k1
+        fk1 = jnp.fft.rfft2(k1)
+        fk2 = jnp.fft.rfft2(k2)
+        gpad = jnp.pad(grid, ((0, big - nodes), (0, big - nodes), (0, 0)))
+        fg = jnp.fft.rfft2(gpad, axes=(0, 1))
+        pot2 = jnp.fft.irfft2(fg * fk2[:, :, None], s=(big, big),
+                              axes=(0, 1))[:nodes, :nodes]
+        pot1 = jnp.fft.irfft2(fg[..., 0] * fk1, s=(big, big))[:nodes, :nodes]
 
     # gather all four potentials back at the points in one pass:
     # channels = {sum K2, sum K2*yx, sum K2*yy, sum K1 (incl self)}
-    pot_all = jnp.concatenate([pot2, pot1[:, :, None]], axis=2)
-    phi = gather(pot_all, base, wx, wy)                    # [N, 4]
-    phi2_1, phi2_x, phi2_y, phi1_1 = (phi[:, 0], phi[:, 1], phi[:, 2], phi[:, 3])
-
-    z = jnp.sum(phi1_1) - n                                # remove self terms
-    fx = y[:, 0] * phi2_1 - phi2_x                         # self term cancels
-    fy = y[:, 1] * phi2_1 - phi2_y
-    return jnp.stack([fx, fy], axis=1), jnp.maximum(z, 1e-30)
+    with jax.named_scope(scopes.FFT_GATHER):
+        pot_all = jnp.concatenate([pot2, pot1[:, :, None]], axis=2)
+        phi = gather(pot_all, base, wx, wy)                # [N, 4]
+        phi2_1, phi2_x, phi2_y, phi1_1 = (phi[:, 0], phi[:, 1], phi[:, 2],
+                                          phi[:, 3])
+        z = jnp.sum(phi1_1) - n                            # remove self terms
+        fx = y[:, 0] * phi2_1 - phi2_x                     # self term cancels
+        fy = y[:, 1] * phi2_1 - phi2_y
+        return jnp.stack([fx, fy], axis=1), jnp.maximum(z, 1e-30)

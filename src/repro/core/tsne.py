@@ -23,7 +23,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
-from repro.core import attractive, bsp, morton, quadtree, similarity
+from repro.core import attractive, bsp, morton, quadtree, scopes, similarity
 from repro.core.summarize import summarize as _summarize
 from repro.core.repulsive import bh_repulsion_sorted
 
@@ -163,6 +163,10 @@ class GradResult(NamedTuple):
     kl: jax.Array          # KL(P||Q) estimate (exact attractive part, backend Z)
     z: jax.Array
     max_traversal: jax.Array  # BH tree-walk depth; 0 for tree-free backends
+    # mean BH walk length over points; 0 for tree-free backends.  Over
+    # max_traversal it is the share of the lockstep walk's lane-turns that
+    # did work.
+    mean_traversal: jax.Array | float = 0.0
 
 
 @jax.tree_util.register_dataclass
@@ -190,20 +194,25 @@ class NeighborGraph:
 
 def combine_forces(
     f_attr, kl_attr, f_rep_unnorm, z, exaggeration, p_logp,
-    max_traversal=None,
+    max_traversal=None, mean_traversal=None,
 ) -> GradResult:
     """Shared backend epilogue (eq. 6/7): fold attractive + repulsive terms.
 
     grad = 4 (exag * F_attr - F_rep / Z);  KL = sum p log p + kl_attr + log Z.
     ``f_rep_unnorm`` is the un-normalized repulsive numerator.
     """
-    dtype = f_attr.dtype
-    z = jnp.maximum(z, 1e-30)
-    grad = 4.0 * (jnp.asarray(exaggeration, dtype) * f_attr - f_rep_unnorm / z)
-    kl = p_logp + kl_attr + jnp.log(z)
+    with jax.named_scope(scopes.UPDATE):
+        dtype = f_attr.dtype
+        z = jnp.maximum(z, 1e-30)
+        grad = 4.0 * (jnp.asarray(exaggeration, dtype) * f_attr
+                      - f_rep_unnorm / z)
+        kl = p_logp + kl_attr + jnp.log(z)
     if max_traversal is None:
         max_traversal = jnp.zeros((), jnp.int32)
-    return GradResult(grad=grad, kl=kl, z=z, max_traversal=max_traversal)
+    if mean_traversal is None:
+        mean_traversal = jnp.zeros((), dtype)
+    return GradResult(grad=grad, kl=kl, z=z, max_traversal=max_traversal,
+                      mean_traversal=mean_traversal)
 
 
 # ---------------------------------------------------------------------------
@@ -225,36 +234,44 @@ def bh_gradient(
     attractive_block: int = 512,
 ) -> GradResult:
     # --- quadtree building (step 3) ---
-    cent, r_span = morton.span_radius(y)
-    if use_pallas:
-        from repro.kernels.ops import morton_encode as enc
-        codes = enc(y, cent, r_span, depth=depth)
-    else:
-        codes = morton.morton_encode(y, cent, r_span, depth=depth)
-    codes_s, y_s, perm = quadtree.sort_points_by_code(y, codes)
-    tree = quadtree.build_quadtree(codes_s, depth=depth, compress=compress_tree)
-    # --- summarization (step 4) ---
-    summ = _summarize(tree, y_s, r_span)
-    # --- repulsive (step 6) ---
-    rep = bh_repulsion_sorted(y_s, tree, summ, theta)
-    z = jnp.sum(rep.z_per_point)
-    f_rep = jnp.zeros_like(y).at[perm].set(rep.force)
-    # --- attractive (step 5) ---
-    if edges is not None:
-        f_attr, kl_attr = attractive.attractive_forces_edges(y, *edges)
-    else:
+    with jax.named_scope(scopes.BH_TREE):
+        cent, r_span = morton.span_radius(y)
         if use_pallas:
-            from repro.kernels.ops import attractive_forces_ell as attr_ell
-        elif attractive_impl == "blocked":
-            attr_ell = functools.partial(
-                attractive.attractive_forces_ell_blocked,
-                block=attractive_block,
-            )
+            from repro.kernels.ops import morton_encode as enc
+            codes = enc(y, cent, r_span, depth=depth)
         else:
-            attr_ell = attractive.ell_impl(attractive_impl)
-        f_attr, kl_attr = attr_ell(y, p_cols, p_vals)
+            codes = morton.morton_encode(y, cent, r_span, depth=depth)
+        codes_s, y_s, perm = quadtree.sort_points_by_code(y, codes)
+        tree = quadtree.build_quadtree(codes_s, depth=depth,
+                                       compress=compress_tree)
+    # --- summarization (step 4) ---
+    with jax.named_scope(scopes.BH_SUMMARIZE):
+        summ = _summarize(tree, y_s, r_span)
+    # --- repulsive (step 6) ---
+    with jax.named_scope(scopes.BH_TRAVERSAL):
+        rep = bh_repulsion_sorted(y_s, tree, summ, theta)
+        z = jnp.sum(rep.z_per_point)
+        f_rep = jnp.zeros_like(y).at[perm].set(rep.force)
+        max_traversal = jnp.max(rep.steps)
+        mean_traversal = jnp.mean(rep.steps.astype(y.dtype))
+    # --- attractive (step 5) ---
+    with jax.named_scope(scopes.ATTRACTIVE):
+        if edges is not None:
+            f_attr, kl_attr = attractive.attractive_forces_edges(y, *edges)
+        else:
+            if use_pallas:
+                from repro.kernels.ops import attractive_forces_ell as attr_ell
+            elif attractive_impl == "blocked":
+                attr_ell = functools.partial(
+                    attractive.attractive_forces_ell_blocked,
+                    block=attractive_block,
+                )
+            else:
+                attr_ell = attractive.ell_impl(attractive_impl)
+            f_attr, kl_attr = attr_ell(y, p_cols, p_vals)
     return combine_forces(f_attr, kl_attr, f_rep, z, exaggeration, p_logp,
-                          max_traversal=jnp.max(rep.steps))
+                          max_traversal=max_traversal,
+                          mean_traversal=mean_traversal)
 
 
 # ---------------------------------------------------------------------------
@@ -277,6 +294,7 @@ class StepStats(NamedTuple):
     grad_norm: jax.Array
     z: jax.Array
     max_traversal: jax.Array
+    mean_traversal: jax.Array | float = 0.0
 
 
 @functools.partial(jax.jit, static_argnames=("backend", "lr", "min_gain"))
@@ -302,10 +320,12 @@ def tsne_step(
         lr, min_gain,
     )
     res = backend.gradient(state.y, graph, exaggeration)
-    grad_norm = jnp.linalg.norm(res.grad)
-    new_state = gd_update(state, res.grad, lr, momentum, min_gain)
+    with jax.named_scope(scopes.UPDATE):
+        grad_norm = jnp.linalg.norm(res.grad)
+        new_state = gd_update(state, res.grad, lr, momentum, min_gain)
     return new_state, StepStats(kl=res.kl, grad_norm=grad_norm, z=res.z,
-                                max_traversal=res.max_traversal)
+                                max_traversal=res.max_traversal,
+                                mean_traversal=res.mean_traversal)
 
 
 # ---------------------------------------------------------------------------
@@ -334,6 +354,7 @@ class IterationStats:
     exaggeration: float
     momentum: float
     elapsed_s: float        # wall time since gradient descent started
+    mean_traversal: float = 0.0  # mean BH tree walk over points (0 likewise)
 
 
 ObserverFn = Callable[[IterationStats], None]
@@ -392,52 +413,50 @@ def preprocess(
             )
         sp_bsp.sync(cond_p)
 
-    sp_sym_ctx = timer.span("symmetrize", layout=config.attractive_impl,
-                            chunk_size=chunk)
-    sp_sym = sp_sym_ctx.__enter__()
     n = int(x.shape[0])
-    if config.attractive_impl == "edges":
-        # edge layout: ship only the directed edge list ([N, W] ELL planes
-        # would ride along as dead jit operands of every step).  The exact
-        # KL constant comes from an ordered-pair dedup: mutual KNN edges sum
-        # to the symmetric p_ij = (p_{j|i} + p_{i|j}) / 2N.
-        src, dst, w = similarity.edge_list(idx, cond_p)
-        s = np.asarray(src, np.int64)
-        d = np.asarray(dst, np.int64)
-        wv = np.asarray(w, np.float64)
-        key = np.concatenate([s * n + d, d * n + s])
-        val = np.concatenate([wv, wv])
-        _, inv = np.unique(key, return_inverse=True)
-        p = np.bincount(inv, weights=val)
-        p = p / p.sum()
-        p_logp = float((p[p > 0] * np.log(p[p > 0])).sum())
-        has_edges = True
-        p_cols = jnp.zeros((1, 1), jnp.int32)
-        p_vals = jnp.zeros((1, 1), config.dtype)
-    else:
-        if chunk is not None:
-            sym_cols, sym_vals = similarity.symmetrize_ell_chunked(
-                idx, cond_p, chunk
-            )
+    with timer.span("symmetrize", layout=config.attractive_impl,
+                    chunk_size=chunk) as sp_sym:
+        if config.attractive_impl == "edges":
+            # edge layout: ship only the directed edge list ([N, W] ELL
+            # planes would ride along as dead jit operands of every step).
+            # The exact KL constant comes from an ordered-pair dedup: mutual
+            # KNN edges sum to the symmetric p_ij = (p_{j|i} + p_{i|j}) / 2N.
+            src, dst, w = similarity.edge_list(idx, cond_p)
+            s = np.asarray(src, np.int64)
+            d = np.asarray(dst, np.int64)
+            wv = np.asarray(w, np.float64)
+            key = np.concatenate([s * n + d, d * n + s])
+            val = np.concatenate([wv, wv])
+            _, inv = np.unique(key, return_inverse=True)
+            p = np.bincount(inv, weights=val)
+            p = p / p.sum()
+            p_logp = float((p[p > 0] * np.log(p[p > 0])).sum())
+            has_edges = True
+            p_cols = jnp.zeros((1, 1), jnp.int32)
+            p_vals = jnp.zeros((1, 1), config.dtype)
         else:
-            sym_cols, sym_vals = similarity.symmetrize_ell(idx, cond_p)
-        sym_vals = sym_vals / sym_vals.sum()
-        pv = np.asarray(sym_vals)
-        p_logp = float((pv[pv > 0] * np.log(pv[pv > 0])).sum())
-        src = dst = jnp.zeros((1,), jnp.int32)
-        w = jnp.zeros((1,), config.dtype)
-        has_edges = False
-        p_cols = jnp.asarray(sym_cols)
-        p_vals = jnp.asarray(sym_vals, config.dtype)
-    graph = NeighborGraph(
-        p_cols=p_cols, p_vals=p_vals,
-        edge_src=src, edge_dst=dst, edge_w=w,
-        p_logp=jnp.asarray(p_logp, config.dtype),
-        n=n,
-        has_edges=has_edges,
-    )
-    sp_sym.sync((graph.p_vals, graph.edge_w))
-    sp_sym_ctx.__exit__(None, None, None)
+            if chunk is not None:
+                sym_cols, sym_vals = similarity.symmetrize_ell_chunked(
+                    idx, cond_p, chunk
+                )
+            else:
+                sym_cols, sym_vals = similarity.symmetrize_ell(idx, cond_p)
+            sym_vals = sym_vals / sym_vals.sum()
+            pv = np.asarray(sym_vals)
+            p_logp = float((pv[pv > 0] * np.log(pv[pv > 0])).sum())
+            src = dst = jnp.zeros((1,), jnp.int32)
+            w = jnp.zeros((1,), config.dtype)
+            has_edges = False
+            p_cols = jnp.asarray(sym_cols)
+            p_vals = jnp.asarray(sym_vals, config.dtype)
+        graph = NeighborGraph(
+            p_cols=p_cols, p_vals=p_vals,
+            edge_src=src, edge_dst=dst, edge_w=w,
+            p_logp=jnp.asarray(p_logp, config.dtype),
+            n=n,
+            has_edges=has_edges,
+        )
+        sp_sym.sync((graph.p_vals, graph.edge_w))
     return graph, dict(
         knn=sp_knn.duration_s, bsp=sp_bsp.duration_s,
         symmetrize=sp_sym.duration_s,
@@ -478,13 +497,21 @@ def run_tsne(
 
     Observability: the run is one ``fit`` span with ``knn`` / ``bsp`` /
     ``symmetrize`` / ``gradient_descent`` children (the descent splits into
-    ``early_exaggeration`` / ``main_phase``, with a zero-ish-width
-    ``checkpoint`` span per KL evaluation carrying kl / grad-norm / mean
-    gain), all on ``tracer`` — default the process-global one, a no-op
-    unless enabled.  The returned ``timings`` dict is *derived from those
-    spans*, so the Perfetto trace and ``timings_`` can never disagree.
-    Checkpoint stats also land on ``metrics`` (default global registry) as
-    ``fit.grad_norm`` / ``fit.gain_mean`` histograms and ``fit.kl`` gauge.
+    ``early_exaggeration`` / ``main_phase``, each iteration's dispatch is a
+    ``step`` span carrying its iteration number, and each KL evaluation a
+    ``checkpoint`` span over its host work: the stats pull, the metrics and
+    the observer, carrying kl / grad-norm / Z, and the mean gain when the
+    caller's tracer is enabled), all on ``tracer`` — default the
+    process-global one, a no-op unless enabled.  Every span is also a
+    ``jax.profiler`` annotation, so these phases appear in any JAX profile.
+    The seconds in the returned ``timings`` dict are *derived from those
+    spans*, so the Perfetto trace and ``timings_`` can never disagree on a
+    phase's time.  Besides them it lists the Barnes-Hut walk at each
+    checkpoint, from the checkpoint's stats and not from a span
+    (``max_traversal``: the lockstep walk's turns; ``mean_traversal``: the
+    mean over points; 0 without a tree).  Checkpoint stats also land on ``metrics`` (default global
+    registry) as ``fit.grad_norm`` / ``fit.gain_mean`` histograms and a
+    ``fit.kl`` gauge.
     """
     x = jnp.asarray(x, config.dtype)
     n = x.shape[0]
@@ -494,11 +521,12 @@ def run_tsne(
     if metrics is None:
         metrics = obs.get_metrics()
     timer = tracer if tracer.enabled else obs.Tracer()
+    n_early = min(config.exaggeration_iters, config.n_iter)
+    phases = (("early_exaggeration", 0, n_early, config.early_exaggeration),
+              ("main_phase", n_early, config.n_iter, 1.0))
 
-    fit_ctx = timer.span("fit", n=int(n), method=config.method,
-                         neighbor_method=config.neighbor_method)
-    fit_ctx.__enter__()
-    try:
+    with timer.span("fit", n=int(n), method=config.method,
+                    neighbor_method=config.neighbor_method):
         graph, timings = preprocess(x, config, tracer=timer)
         state = init_state(n, config)
 
@@ -508,69 +536,69 @@ def run_tsne(
         step_kw = dict(backend=backend, lr=lr, min_gain=config.min_gain)
 
         kl_hist = []
-        gd_ctx = timer.span("gradient_descent", n_iter=config.n_iter, lr=lr)
-        sp_gd = gd_ctx.__enter__()
-        t0 = sp_gd.t0
+        max_walk: list[int] = []
+        mean_walk: list[float] = []
         kl = float("nan")
         it = 0
-        phase_name: str | None = None
-        phase_ctx = phase_sp = None
-        try:
-            for it in range(config.n_iter):
-                exag = config.early_exaggeration if it < config.exaggeration_iters else 1.0
-                mom = config.momentum_initial if it < config.momentum_switch_iter else config.momentum_final
-                want = "early_exaggeration" if it < config.exaggeration_iters \
-                    else "main_phase"
-                if want != phase_name:
-                    if phase_ctx is not None:
-                        phase_sp.sync(state.y)
-                        phase_ctx.__exit__(None, None, None)
-                    phase_ctx = timer.span(want, start_iter=it,
-                                           exaggeration=exag)
-                    phase_sp = phase_ctx.__enter__()
-                    phase_name = want
-                state, stats = tsne_step(
-                    state, graph,
-                    jnp.asarray(exag, config.dtype), jnp.asarray(mom, config.dtype),
-                    **step_kw,
-                )
-                if (it + 1) % kl_every == 0 or it == config.n_iter - 1:
-                    kl = float(stats.kl)
-                    grad_norm = float(stats.grad_norm)
-                    kl_hist.append((it + 1, kl))
-                    metrics.histogram("fit.grad_norm").observe(grad_norm)
-                    metrics.gauge("fit.kl").set(kl)
-                    metrics.gauge("fit.exaggeration").set(exag)
-                    if timer.enabled and timer is tracer:
-                        # trace-only extras (one extra device pull)
-                        gain_mean = float(jnp.mean(state.gains))
-                        metrics.histogram("fit.gain_mean").observe(gain_mean)
-                        with timer.span(
-                            "checkpoint", iteration=it + 1, kl=kl,
-                            grad_norm=grad_norm, z=float(stats.z),
-                            exaggeration=exag, momentum=mom,
-                            gain_mean=gain_mean,
-                        ):
-                            pass
-                    if observer is not None:
-                        observer(IterationStats(
-                            iteration=it + 1, kl=kl, grad_norm=grad_norm,
-                            z=float(stats.z), max_traversal=int(stats.max_traversal),
-                            exaggeration=exag, momentum=mom,
-                            elapsed_s=time.perf_counter() - t0,
-                        ))
-                    if grad_norm < config.min_grad_norm:
-                        break
-        finally:
-            if phase_ctx is not None:
-                phase_sp.sync(state.y)
-                phase_ctx.__exit__(None, None, None)
+        converged = False
+        with timer.span("gradient_descent", n_iter=config.n_iter,
+                        lr=lr) as sp_gd:
+            for phase, start, stop, exag in phases:
+                if start >= stop or converged:
+                    continue
+                with timer.span(phase, start_iter=start,
+                                exaggeration=exag) as sp_phase:
+                    for it in range(start, stop):
+                        mom = config.momentum_initial \
+                            if it < config.momentum_switch_iter \
+                            else config.momentum_final
+                        with timer.span("step", step_num=it + 1):
+                            state, stats = tsne_step(
+                                state, graph,
+                                jnp.asarray(exag, config.dtype),
+                                jnp.asarray(mom, config.dtype),
+                                **step_kw,
+                            )
+                        if (it + 1) % kl_every and it != config.n_iter - 1:
+                            continue
+                        with timer.span("checkpoint",
+                                        iteration=it + 1) as sp_ck:
+                            got = jax.device_get(stats)     # one pull
+                            kl = float(got.kl)
+                            grad_norm = float(got.grad_norm)
+                            z = float(got.z)
+                            kl_hist.append((it + 1, kl))
+                            max_walk.append(int(got.max_traversal))
+                            mean_walk.append(float(got.mean_traversal))
+                            metrics.histogram("fit.grad_norm").observe(
+                                grad_norm)
+                            metrics.gauge("fit.kl").set(kl)
+                            sp_ck.annotate(kl=kl, grad_norm=grad_norm, z=z,
+                                           exaggeration=exag, momentum=mom)
+                            if timer is tracer:
+                                # trace-only extra (one more device pull)
+                                gain_mean = float(jnp.mean(state.gains))
+                                metrics.histogram("fit.gain_mean").observe(
+                                    gain_mean)
+                                sp_ck.annotate(gain_mean=gain_mean)
+                            if observer is not None:
+                                observer(IterationStats(
+                                    iteration=it + 1, kl=kl,
+                                    grad_norm=grad_norm, z=z,
+                                    max_traversal=max_walk[-1],
+                                    exaggeration=exag, momentum=mom,
+                                    elapsed_s=time.perf_counter() - sp_gd.t0,
+                                    mean_traversal=mean_walk[-1],
+                                ))
+                        if grad_norm < config.min_grad_norm:
+                            converged = True
+                            break
+                    sp_phase.sync(state.y)
             sp_gd.sync(state.y)
-            gd_ctx.__exit__(None, None, None)
         timings["gradient_descent"] = sp_gd.duration_s
+        timings["max_traversal"] = max_walk
+        timings["mean_traversal"] = mean_walk
         metrics.counter("fit.iterations").inc(it + 1)
-    finally:
-        fit_ctx.__exit__(None, None, None)
     return TsneResult(
         y=np.asarray(state.y),
         kl=kl,

@@ -9,7 +9,8 @@ Tables 5/6) as first-class, reproducible artifacts:
 * :class:`MetricsRegistry` — counters, gauges, bounded histograms with
   p50/p95/p99;
 * :class:`RecompileProbe` — one count per distinct jit trace;
-* sinks — JSONL event logs and Chrome-trace JSON (Perfetto-loadable).
+* a Chrome-trace JSON sink (Perfetto-loadable), and every open span as a
+  ``jax.profiler`` annotation, so a JAX profile shows the same phases.
 
 Everything is **disabled by default with near-zero overhead**.  Three ways
 to turn tracing on:
@@ -63,17 +64,11 @@ def get_metrics() -> MetricsRegistry:
     return _global_metrics
 
 
-def trace(name: str, **attrs):
-    """Open a span on the global tracer — ``with trace("knn") as sp:``.
-    A no-op (shared null span) while the global tracer is disabled."""
-    return _global_tracer.span(name, **attrs)
-
-
 # imported late: RecompileProbe registers on the global metrics registry
 from repro.obs.recompile import RecompileProbe  # noqa: E402
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry",
     "NULL_SPAN", "Span", "Tracer", "RecompileProbe",
-    "env_trace_enabled", "get_metrics", "get_tracer", "set_tracer", "trace",
+    "env_trace_enabled", "get_metrics", "get_tracer", "set_tracer",
 ]

@@ -14,9 +14,7 @@ get-or-create by name on a :class:`MetricsRegistry`:
 Histograms keep a **bounded** sample reservoir (ring overwrite past
 ``max_samples``) so long-running services never grow unbounded, while
 count/sum/min/max stay exact; percentiles (p50/p95/p99) are computed over
-the retained window.  Registries merge (:meth:`MetricsRegistry.merge`):
-counters add, gauges take the other's latest value, histograms pool their
-retained samples — the worker-aggregation primitive.
+the retained window.
 """
 from __future__ import annotations
 
@@ -36,9 +34,6 @@ class Counter:
         self.value += n
         return self.value
 
-    def merge(self, other: "Counter") -> None:
-        self.value += other.value
-
 
 class Gauge:
     """Last-set instantaneous value; tracks the high-water mark."""
@@ -57,13 +52,6 @@ class Gauge:
         if self.value > self.max_value:
             self.max_value = self.value
         return self.value
-
-    def merge(self, other: "Gauge") -> None:
-        if other.n_sets:
-            self.value = other.value
-            self.n_sets += other.n_sets
-        if other.max_value > self.max_value:
-            self.max_value = other.max_value
 
 
 class Histogram:
@@ -129,18 +117,6 @@ class Histogram:
             p99=self.percentile(99),
         )
 
-    def merge(self, other: "Histogram") -> None:
-        self.count += other.count
-        self.total += other.total
-        self.min = min(self.min, other.min)
-        self.max = max(self.max, other.max)
-        for v in other._samples:
-            if len(self._samples) < self.max_samples:
-                self._samples.append(v)
-            else:
-                self._samples[self._next] = v
-                self._next = (self._next + 1) % self.max_samples
-
 
 class MetricsRegistry:
     """Named instruments, get-or-create; snapshot() is JSON-ready."""
@@ -173,17 +149,6 @@ class MetricsRegistry:
         (e.g. ``counter_values("recompiles.")`` -> per-probe trace counts)."""
         return {name: c.value for name, c in sorted(self._counters.items())
                 if name.startswith(prefix)}
-
-    def merge(self, other: "MetricsRegistry") -> "MetricsRegistry":
-        """Fold ``other`` in: counters add, gauges take other's last set
-        value (high-water marks max), histograms pool retained samples."""
-        for name, c in other._counters.items():
-            self.counter(name).merge(c)
-        for name, g in other._gauges.items():
-            self.gauge(name).merge(g)
-        for name, h in other._histograms.items():
-            self.histogram(name, h.max_samples).merge(h)
-        return self
 
     def snapshot(self) -> dict:
         out: dict = {}

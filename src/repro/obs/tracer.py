@@ -24,19 +24,28 @@ Two properties matter on a JAX hot path:
   one reusable no-op context manager (no allocation, no clock read), so
   instrumentation can stay in production code unconditionally.
 
+While a span is open it is also a ``jax.profiler.TraceAnnotation`` of the
+same name (a ``StepTraceAnnotation`` for a span opened with ``step_num``),
+so the program's phases appear in any JAX profile on the device trace's
+clock, over the idle gaps they cause.  With no profile running an
+annotation is one flag check.
+
 Spans nest through a per-thread stack; each completed span records its
-parent index and depth, which the exporters turn into a hierarchy:
-:meth:`Tracer.to_jsonl` writes one JSON object per span, and
-:meth:`Tracer.to_chrome_trace` writes Chrome-trace JSON (``traceEvents``
-with ``ph: "X"`` complete events) loadable in Perfetto / ``chrome://tracing``.
+parent index and depth, which :meth:`Tracer.to_chrome_trace` turns into
+Chrome-trace JSON (``traceEvents`` with ``ph: "X"`` complete events)
+loadable in Perfetto / ``chrome://tracing``.
 """
 from __future__ import annotations
 
+import itertools
 import json
 import os
 import threading
 import time
 from typing import Any, Callable
+
+import jax
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 
 class _NullSpan:
@@ -62,24 +71,28 @@ NULL_SPAN = _NullSpan()
 
 
 class Span:
-    """One timed region.  Created by :meth:`Tracer.span`; closed by the
-    context manager, which first blocks on every pytree registered through
-    :meth:`sync` so asynchronously dispatched device work lands inside the
+    """One timed region.  Created by :meth:`Tracer.span` and closed by the
+    ``with`` block, which first blocks on every pytree registered through
+    :meth:`sync`, so asynchronously dispatched device work lands inside the
     span that launched it."""
 
     __slots__ = ("name", "t0", "t1", "depth", "index", "parent", "attrs",
-                 "_sync_targets")
+                 "_tracer", "_stack", "_annotation", "_sync_targets")
     enabled = True
 
-    def __init__(self, name: str, t0: float, depth: int, index: int,
-                 parent: int, attrs: dict):
+    def __init__(self, tracer: "Tracer", stack: list, name: str, t0: float,
+                 index: int, attrs: dict):
         self.name = name
         self.t0 = t0
         self.t1: float | None = None
-        self.depth = depth
+        self.depth = len(stack)
         self.index = index
-        self.parent = parent          # index of enclosing span, -1 at root
-        self.attrs = attrs
+        # index of enclosing span, -1 at root
+        self.parent = stack[-1].index if stack else -1
+        self.attrs = attrs            # "step_num" makes it a profiler step
+        self._tracer = tracer
+        self._stack = stack           # the opening thread's span stack
+        self._annotation = None
         self._sync_targets: list = []
 
     def annotate(self, **attrs) -> "Span":
@@ -93,44 +106,45 @@ class Span:
         self._sync_targets.append(value)
         return value
 
+    def __enter__(self) -> "Span":
+        step_num = self.attrs.get("step_num")
+        if step_num is None:
+            self._annotation = TraceAnnotation(self.name)
+        else:
+            self._annotation = StepTraceAnnotation(self.name,
+                                                   step_num=step_num)
+        self._annotation.__enter__()
+        self._stack.append(self)
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        try:
+            for target in self._sync_targets:
+                jax.block_until_ready(target)
+        finally:
+            self._sync_targets.clear()
+            self.t1 = self._tracer._clock()
+            if self._stack and self._stack[-1] is self:
+                self._stack.pop()
+            # list.append is atomic: spans closing on several threads
+            # need no lock
+            self._tracer.spans.append(self)
+            self._annotation.__exit__(None, None, None)
+        return False
+
     @property
     def duration_s(self) -> float:
         if self.t1 is None:
             raise RuntimeError(f"span {self.name!r} is still open")
         return self.t1 - self.t0
 
-    def to_dict(self) -> dict:
-        d = dict(name=self.name, ts=self.t0, dur=self.duration_s,
-                 depth=self.depth, index=self.index, parent=self.parent)
-        if self.attrs:
-            d["attrs"] = self.attrs
-        return d
-
-
-class _SpanCtx:
-    """Binds one Span to a (tracer, thread-stack) for with-statement use."""
-
-    __slots__ = ("_tracer", "_span")
-
-    def __init__(self, tracer: "Tracer", span: Span):
-        self._tracer = tracer
-        self._span = span
-
-    def __enter__(self) -> Span:
-        self._tracer._push(self._span)
-        return self._span
-
-    def __exit__(self, *exc) -> bool:
-        self._tracer._pop(self._span)
-        return False
-
 
 class Tracer:
-    """Collects nested spans; export through :meth:`to_jsonl` /
-    :meth:`to_chrome_trace`, aggregate through :meth:`durations`.
+    """Collects nested spans; export through :meth:`to_chrome_trace`,
+    aggregate through :meth:`durations`.
 
     Thread-safe: each thread nests on its own stack (Chrome-trace ``tid``),
-    completed spans append under a lock.  ``clock`` is injectable for
+    completed spans append to one list.  ``clock`` is injectable for
     deterministic tests.
     """
 
@@ -140,8 +154,7 @@ class Tracer:
         self._clock = clock
         self.spans: list[Span] = []      # completed, in close order
         self._local = threading.local()
-        self._lock = threading.Lock()
-        self._n_started = 0
+        self._indices = itertools.count()
         self.t_epoch = clock()           # ts base for exported traces
 
     # ------------------------------------------------------------ record --
@@ -152,45 +165,26 @@ class Tracer:
             st = self._local.stack = []
         return st
 
-    def span(self, name: str, **attrs):
-        """Open a span named ``name``.  Disabled tracers return the shared
+    def span(self, name: str, step_num: int | None = None, **attrs):
+        """Open a span named ``name``; with ``step_num`` it is also a
+        profiler step of that number.  Disabled tracers return the shared
         no-op span — zero allocation, no clock read."""
         if not self.enabled:
             return NULL_SPAN
-        stack = self._stack()
-        parent = stack[-1].index if stack else -1
-        with self._lock:
-            index = self._n_started
-            self._n_started += 1
-        sp = Span(name, self._clock(), depth=len(stack), index=index,
-                  parent=parent, attrs=dict(attrs))
-        return _SpanCtx(self, sp)
-
-    def _push(self, sp: Span) -> None:
-        self._stack().append(sp)
-
-    def _pop(self, sp: Span) -> None:
-        if sp._sync_targets:
-            import jax
-            for target in sp._sync_targets:
-                jax.block_until_ready(target)
-            sp._sync_targets.clear()
-        sp.t1 = self._clock()
-        stack = self._stack()
-        if stack and stack[-1] is sp:
-            stack.pop()
-        with self._lock:
-            self.spans.append(sp)
+        if step_num is not None:
+            attrs["step_num"] = step_num
+        # next() on an itertools.count is atomic: indices stay unique
+        # across threads
+        return Span(self, self._stack(), name, self._clock(),
+                    next(self._indices), attrs)
 
     def clear(self) -> None:
-        with self._lock:
-            self.spans.clear()
+        self.spans.clear()
 
     def _snapshot(self) -> list[Span]:
-        """Consistent copy of the completed spans (``_pop`` appends from
-        worker threads under the same lock)."""
-        with self._lock:
-            return list(self.spans)
+        """A copy of the completed spans (worker threads append to the
+        list while it is read)."""
+        return list(self.spans)
 
     # ----------------------------------------------------------- inspect --
 
@@ -211,15 +205,6 @@ class Tracer:
         return out
 
     # ------------------------------------------------------------ export --
-
-    def to_jsonl(self, path) -> None:
-        """One JSON object per completed span (ts/dur in seconds, relative
-        to the tracer epoch)."""
-        with open(path, "w") as f:
-            for s in self._snapshot():
-                d = s.to_dict()
-                d["ts"] = d["ts"] - self.t_epoch
-                f.write(json.dumps(d) + "\n")
 
     def chrome_trace(self, process_name: str = "tsne") -> dict:
         """Chrome-trace dict: ``traceEvents`` of complete (``ph: "X"``)

@@ -1,4 +1,6 @@
 """Core BH t-SNE correctness: every step validated against the exact oracle."""
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -396,3 +398,92 @@ class TestEndToEnd:
             kl[impl] = run_tsne(x, cfg, kl_every=150).kl_history[-1, 1]
         # identical forces; KL differs only by the constant-sum-p-log-p estimate
         assert abs(kl["ell"] - kl["edges"]) < 0.5
+
+
+# --------------------------------------------------- step layers, counters ---
+def _numpy_walk(y_s, tree, summ, theta):
+    """Each point's turns of the rope walk, step for step in NumPy."""
+    start, end = np.asarray(tree.start), np.asarray(tree.end)
+    skip, n_nodes = np.asarray(tree.skip), int(tree.n_nodes)
+    is_leaf = skip == np.arange(skip.shape[0]) + 1
+    count, sum_y = np.asarray(summ.count), np.asarray(summ.sum_y)
+    side = np.asarray(summ.side)
+    theta2 = np.float32(theta) ** 2
+    steps = []
+    for p, yp in enumerate(np.asarray(y_s)):
+        ptr = turns = 0
+        while ptr < n_nodes:
+            inside = start[ptr] <= p < end[ptr]
+            cnt = count[ptr] - np.float32(inside)
+            com = (sum_y[ptr] - (yp if inside else 0)) / max(cnt, 1)
+            d2 = np.sum((yp - com) ** 2)
+            opened = not is_leaf[ptr] and side[ptr] ** 2 >= theta2 * d2
+            ptr = ptr + 1 if opened else skip[ptr]
+            turns += 1
+        steps.append(turns)
+    return np.asarray(steps)
+
+
+class TestStepLayers:
+    @pytest.mark.parametrize("method,scoped", [
+        ("barnes_hut", ("bh_tree", "bh_summarize", "bh_traversal",
+                        "attractive", "update")),
+        ("fft", ("fft_spread", "fft_convolve", "fft_gather", "attractive",
+                 "update")),
+    ])
+    def test_scopes_in_lowered_step(self, method, scoped):
+        from repro.api.backends import make_backend
+        from repro.core import scopes
+        from repro.core.tsne import init_state, preprocess, tsne_step
+
+        x, _ = make_points(120, seed=3, dim=8)
+        cfg = TsneConfig(perplexity=8.0, method=method, fft_n_boxes=8)
+        graph, _ = preprocess(jnp.asarray(x), cfg)
+        text = tsne_step.lower(
+            init_state(120, cfg), graph, jnp.float32(12.0), jnp.float32(0.5),
+            backend=make_backend(method, cfg, 120), lr=10.0, min_gain=0.01,
+        ).as_text(debug_info=True)
+        assert set(scoped) <= set(scopes.STEP_SCOPES)
+        for name in scopes.STEP_SCOPES:
+            # a scope heads or continues an op's name path
+            found = re.search(rf'["/]{name}/', text) is not None
+            assert found == (name in scoped), name
+
+    @pytest.mark.parametrize("seed", [5, 6])
+    def test_walk_counters_match_numpy_walk(self, seed):
+        theta = 0.5
+        y, _ = make_points(160, seed=seed)
+        yj = jnp.asarray(y)
+        cent, r = span_radius(yj)
+        cs, ys, _ = sort_points_by_code(yj, morton_encode(yj, cent, r))
+        tree = build_quadtree(cs)
+        turns = _numpy_walk(ys, tree, summarize(tree, ys, r), theta)
+        rows = jnp.arange(160, dtype=jnp.int32)[:, None]
+        res = bh_gradient(yj, rows, jnp.zeros((160, 1), jnp.float32), None,
+                          theta=theta, exaggeration=1.0, depth=DEFAULT_DEPTH,
+                          p_logp=0.0)
+        assert int(res.max_traversal) == turns.max()
+        np.testing.assert_allclose(float(res.mean_traversal), turns.mean(),
+                                   rtol=1e-6)
+        assert 0 < float(res.mean_traversal) < int(res.max_traversal)
+
+    def test_checkpoints_report_the_walk(self):
+        from repro.api import TSNE
+
+        x, _ = make_points(150, seed=7, dim=8)
+        seen = {}
+        for method in ("barnes_hut", "fft"):
+            stats = []
+            est = TSNE(method=method, perplexity=8.0, n_iter=20, kl_every=10,
+                       random_state=0, backend_options={"fft_n_boxes": 8},
+                       callbacks=(stats.append,)).fit(x)
+            assert [s.iteration for s in stats] == [10, 20]
+            assert est.timings_["max_traversal"] == \
+                [s.max_traversal for s in stats]
+            assert est.timings_["mean_traversal"] == \
+                [s.mean_traversal for s in stats]
+            seen[method] = stats
+        assert all(0 < s.mean_traversal <= s.max_traversal
+                   for s in seen["barnes_hut"])
+        assert all(s.max_traversal == 0 and s.mean_traversal == 0
+                   for s in seen["fft"])

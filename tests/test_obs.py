@@ -87,18 +87,6 @@ class TestTracer:
         assert fit["ts"] <= knn["ts"]
         assert knn["ts"] + knn["dur"] <= fit["ts"] + fit["dur"] + 1e-3
 
-    def test_jsonl_sink(self, tmp_path):
-        t = Tracer()
-        with t.span("a", n=1):
-            with t.span("b"):
-                pass
-        path = tmp_path / "spans.jsonl"
-        t.to_jsonl(path)
-        lines = [json.loads(ln) for ln in path.read_text().splitlines()]
-        assert [d["name"] for d in lines] == ["b", "a"]
-        assert lines[1]["attrs"] == {"n": 1}
-        assert all(d["dur"] >= 0 for d in lines)
-
     def test_env_gate(self, monkeypatch):
         for v, want in [("", False), ("0", False), ("false", False),
                         ("off", False), ("1", True), ("yes", True)]:
@@ -148,27 +136,6 @@ class TestMetrics:
         h = Histogram("lat")
         assert math.isnan(h.percentile(50)) and math.isnan(h.mean)
         assert h.summary() == dict(count=0)
-
-    def test_counter_merge(self):
-        a, b = MetricsRegistry(), MetricsRegistry()
-        a.counter("reqs").inc(3)
-        b.counter("reqs").inc(4)
-        b.counter("only_b").inc(1)
-        a.merge(b)
-        assert a.counter("reqs").value == 7
-        assert a.counter("only_b").value == 1
-
-    def test_registry_merge_gauges_histograms(self):
-        a, b = MetricsRegistry(), MetricsRegistry()
-        a.gauge("q").set(5)
-        b.gauge("q").set(2)
-        for v in (1.0, 2.0):
-            a.histogram("h").observe(v)
-        for v in (3.0, 4.0):
-            b.histogram("h").observe(v)
-        a.merge(b)
-        assert a.gauge("q").value == 2 and a.gauge("q").max_value == 5
-        assert a.histogram("h").count == 4 and a.histogram("h").max == 4.0
 
     def test_snapshot_shape(self):
         m = MetricsRegistry()
@@ -245,6 +212,30 @@ class TestTracedFit:
             assert est.timings_[phase] == pytest.approx(d[phase])
             assert est.timings_[phase] > 0
 
+    def test_step_and_checkpoint_spans(self, traced_fit):
+        est, _ = traced_fit
+        tr = est.tracer_
+        phase = tr.last("early_exaggeration")
+        steps = tr.find("step")
+        assert [s.attrs["step_num"] for s in steps] == list(range(1, 61))
+        assert all(s.parent == phase.index for s in steps)
+        ckpts = tr.find("checkpoint")
+        assert [c.attrs["iteration"] for c in ckpts] == [30, 60]
+        assert all({"kl", "grad_norm", "z", "gain_mean"} <= set(c.attrs)
+                   for c in ckpts)
+
+    def test_timings_unchanged_for_normal_fit(self, traced_fit):
+        est, _ = traced_fit
+        assert set(est.timings_) == {
+            "knn", "bsp", "symmetrize", "gradient_descent",
+            "neighbor_method", "n_neighbors", "bsp_impl", "chunk_size",
+            "knn_mean_d2", "max_traversal", "mean_traversal"}
+        d = est.tracer_.durations()
+        assert est.timings_["gradient_descent"] == pytest.approx(
+            d["early_exaggeration"], rel=0.05)
+        assert len(est.timings_["max_traversal"]) == 2
+        assert len(est.timings_["mean_traversal"]) == 2
+
     def test_chrome_trace_written_and_loadable(self, traced_fit):
         _, path = traced_fit
         doc = json.loads(path.read_text())
@@ -268,6 +259,73 @@ class TestTracedFit:
         assert est.tracer_ is None
         for phase in ("knn", "bsp", "symmetrize", "gradient_descent"):
             assert est.timings_[phase] > 0
+
+
+class TestSpanLifecycle:
+    def test_raising_symmetrize_closes_its_span(self, monkeypatch):
+        from repro.core import similarity
+        from repro.core.tsne import TsneConfig, preprocess
+        from repro.data.datasets import make_dataset
+
+        def symmetrize(*args):
+            raise RuntimeError("planted")
+        monkeypatch.setattr(similarity, "symmetrize_ell", symmetrize)
+        x, _ = make_dataset("digits", n=120)
+        t = Tracer()
+        with pytest.raises(RuntimeError, match="planted"):
+            preprocess(jnp.asarray(x), TsneConfig(perplexity=5.0), tracer=t)
+        assert [s.name for s in t.spans] == ["knn", "bsp", "symmetrize"]
+        assert t.last("symmetrize").duration_s >= 0
+        assert t._stack() == []
+
+    def test_raising_step_closes_every_span(self, monkeypatch):
+        from repro.core import tsne
+
+        real = tsne.tsne_step
+
+        def step(state, *args, **kw):
+            if int(state.iteration) == 3:
+                raise RuntimeError("planted")
+            return real(state, *args, **kw)
+        monkeypatch.setattr(tsne, "tsne_step", step)
+        x = np.random.default_rng(0).normal(size=(80, 5)).astype(np.float32)
+        t = Tracer()
+        with pytest.raises(RuntimeError, match="planted"):
+            tsne.run_tsne(x, tsne.TsneConfig(perplexity=5.0, n_iter=10),
+                          tracer=t)
+        assert t._stack() == []
+        assert [s.attrs["step_num"] for s in t.find("step")] == [1, 2, 3, 4]
+        for name in ("fit", "gradient_descent", "early_exaggeration"):
+            assert t.last(name).duration_s > 0
+
+    def test_profile_shows_program_spans(self, tmp_path):
+        from jax.profiler import ProfileData
+
+        from repro.api import TSNE
+        from repro.data.datasets import make_dataset
+
+        x, _ = make_dataset("digits", n=120)
+
+        def fit():
+            TSNE(perplexity=5.0, n_iter=4, kl_every=2, random_state=0).fit(x)
+        fit()                                   # compiles outside the profile
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+        try:
+            fit()
+        finally:
+            jax.profiler.stop_trace()
+        path, = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+        host = [e for plane in ProfileData.from_file(str(path)).planes
+                if plane.name == "/host:CPU"
+                for line in plane.lines for e in line.events]
+        names = {e.name for e in host}
+        assert {"fit", "knn", "bsp", "symmetrize", "gradient_descent",
+                "early_exaggeration", "step", "checkpoint"} <= names
+        steps = [v for e in host if e.name == "step"
+                 for k, v in e.stats if k == "step_num"]
+        assert sorted(steps) == [1, 2, 3, 4]
 
 
 class TestBenchArtifact:

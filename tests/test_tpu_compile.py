@@ -81,3 +81,38 @@ def test_kernel_compiles_for_v5e(name, spec):
     for kwargs, args in _cases(spec)[name]:
         compiled = fn.lower(*args, interpret=False, **kwargs).compile()
         assert "tpu_custom_call" in compiled.as_text(), (name, kwargs)
+
+
+def test_descent_step_layers_survive_the_v5e_compile(spec):
+    """The step's layer scopes reach the v5e-compiled module: each of its
+    top-level loops carries the layer it runs in, where a device profile
+    reads it back."""
+    import re
+
+    from repro.api.backends import make_backend
+    from repro.core import scopes
+    from repro.core.tsne import NeighborGraph, TsneConfig, TsneState, tsne_step
+
+    n, w, i32 = 1797, 150, jnp.int32          # the digits cell
+    state = TsneState(y=spec((n, 2)), velocity=spec((n, 2)),
+                      gains=spec((n, 2)), iteration=spec((), i32))
+    graph = NeighborGraph(
+        p_cols=spec((n, w), i32), p_vals=spec((n, w)), edge_src=spec((1,), i32),
+        edge_dst=spec((1,), i32), edge_w=spec((1,)), p_logp=spec(()), n=n)
+    text = tsne_step.lower(
+        state, graph, spec(()), spec(()),
+        backend=make_backend("barnes_hut", TsneConfig(), n), lr=149.75,
+        min_gain=0.01,
+    ).compile().as_text()
+    entry = text[text.index("\nENTRY "):]
+    entry = entry[:entry.index("\n}")]
+    loops = {}
+    for line in entry.splitlines():
+        if " while(" in line:
+            path = re.search(r'op_name="([^"]*)"', line).group(1).split("/")
+            loops[line.split()[0]] = [p for p in path if p in scopes.STEP_SCOPES]
+    # the tree build's searchsorted, the walk and the attractive row blocks
+    assert sorted(v[-1] for v in loops.values()) == \
+        [scopes.ATTRACTIVE, scopes.BH_TRAVERSAL, scopes.BH_TREE]
+    for name in (scopes.BH_SUMMARIZE, scopes.UPDATE):
+        assert f"/{name}/" in entry, name
