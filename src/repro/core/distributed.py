@@ -37,7 +37,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import attractive, morton, quadtree
 from repro.core._pairwise import pairwise_sq_dists
-from repro.core.repulsive import bh_repulsion_sorted
+from repro.core.repulsive import node_table, walk
 from repro.core.summarize import summarize
 from repro.core.tsne import GradResult
 
@@ -61,38 +61,7 @@ def _local_bh_gradient(y_loc, p_cols, p_vals, p_logp, *, axis, theta, exaggerati
     my_pos = inv[rank * n_loc + jnp.arange(n_loc, dtype=jnp.int32)]
 
     # repulsion for local points only (step 6)
-    theta2 = jnp.asarray(theta, y_loc.dtype) ** 2
-    n_nodes = tree.n_nodes
-    cap = tree.capacity
-    is_leaf = tree.is_leaf
-
-    def traverse(p, yp):
-        def cond(state):
-            return state[0] < n_nodes
-
-        def body(state):
-            ptr, force, z = state
-            kk = jnp.minimum(ptr, cap - 1)
-            s, e = tree.start[kk], tree.end[kk]
-            cnt = summ.count[kk]
-            inside = (s <= p) & (p < e)
-            cnt_eff = cnt - jnp.where(inside, 1.0, 0.0)
-            sum_eff = summ.sum_y[kk] - jnp.where(inside, yp, jnp.zeros_like(yp))
-            com = sum_eff / jnp.maximum(cnt_eff, 1.0)
-            diff = yp - com
-            d2 = jnp.sum(diff * diff)
-            side = summ.side[kk]
-            open_ = (~is_leaf[kk]) & (side * side >= theta2 * d2)
-            w = jnp.where(open_, 0.0, cnt_eff)
-            q = 1.0 / (1.0 + d2)
-            return (jnp.where(open_, ptr + 1, tree.skip[kk]),
-                    force + (w * q * q) * diff, z + w * q)
-
-        init = (jnp.int32(0), jnp.zeros((2,), y_loc.dtype), jnp.asarray(0.0, y_loc.dtype))
-        _, force, z = jax.lax.while_loop(cond, body, init)
-        return force, z
-
-    f_rep, z_loc = jax.vmap(traverse)(my_pos, y_loc)
+    f_rep, z_loc, _ = walk(node_table(tree, summ), tree.n_nodes, theta, my_pos, y_loc)
     z = jnp.maximum(jax.lax.psum(jnp.sum(z_loc), axis), 1e-30)
 
     # attractive for local rows (step 5) — cols are global indices

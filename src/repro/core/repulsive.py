@@ -6,8 +6,12 @@ Morton-ordered node layout for cache locality.  The TPU equivalent is a
 walks ``ptr = open ? ptr+1 : skip[ptr]`` inside a ``lax.while_loop``.  vmapping
 the loop over points gives lockstep masked execution — the accelerator
 analogue of the paper's "structured data locality" DFS (all lanes read from
-the same contiguous node arrays, near the front of the array most of the
-time, which is exactly the locality argument of §3.5 restated for VMEM/HBM).
+the same contiguous node table, near its front most of the time, which is
+exactly the locality argument of §3.5 restated for VMEM/HBM).
+
+Each node's record is one row of that table (:func:`node_table`), so a turn
+is one gather of a row per lane: on a TPU a gather costs about the same per
+index whatever its width, so one row costs about what one field would.
 
 Self-interaction is excluded *exactly*: when the current node's point range
 contains the query point (known from its position in Morton-sorted order) the
@@ -37,6 +41,62 @@ class RepulsionResult(NamedTuple):
     steps: jax.Array       # [N] traversal lengths (perf diagnostic)
 
 
+def node_table(tree: LinearQuadtree, summary: TreeSummary) -> jax.Array:
+    """One float32 row per node: everything a walk turn reads, in one gather.
+
+    Columns ``start, end, skip`` (int32, bit-cast so indices stay exact at any
+    N), ``sum_y[0], sum_y[1], count`` and ``side2``: the squared cell side,
+    or -inf for a leaf, whose opening test then fails at every distance.
+    The eighth column is padding.
+    """
+    as_f32 = lambda a: jax.lax.bitcast_convert_type(a, jnp.float32)
+    side2 = jnp.where(tree.is_leaf, -jnp.inf, summary.side * summary.side)
+    return jnp.stack(
+        [as_f32(tree.start), as_f32(tree.end), as_f32(tree.skip),
+         summary.sum_y[:, 0], summary.sum_y[:, 1], summary.count, side2,
+         jnp.zeros_like(side2)], axis=1).astype(jnp.float32)
+
+
+def walk(table: jax.Array, n_nodes: jax.Array, theta: jax.Array | float,
+         pos: jax.Array, y_query: jax.Array) -> RepulsionResult:
+    """Barnes-Hut repulsion on query points at Morton-sorted positions ``pos``
+    (their own point is excluded), one ``table`` row read per turn."""
+    dtype = y_query.dtype
+    theta2 = jnp.asarray(theta, dtype) ** 2
+    cap = table.shape[0]
+    as_i32 = lambda a: jax.lax.bitcast_convert_type(a, jnp.int32)
+
+    def traverse(p, yp):
+        def cond(state):
+            ptr, _, _, _ = state
+            return ptr < n_nodes
+
+        def body(state):
+            ptr, force, z, steps = state
+            row = table[jnp.minimum(ptr, cap - 1)]
+            s, e, skip = as_i32(row[0]), as_i32(row[1]), as_i32(row[2])
+            inside = (s <= p) & (p < e)
+            cnt_eff = row[5] - jnp.where(inside, jnp.asarray(1.0, dtype), 0.0)
+            sum_eff = row[3:5] - jnp.where(inside, yp, jnp.zeros_like(yp))
+            com = sum_eff / jnp.maximum(cnt_eff, 1.0)
+            diff = yp - com
+            d2 = jnp.sum(diff * diff)
+            open_ = row[6] >= theta2 * d2               # never for a leaf
+            w = jnp.where(open_, 0.0, cnt_eff)          # contribute iff accepted
+            q = 1.0 / (1.0 + d2)
+            z = z + w * q
+            force = force + (w * q * q) * diff
+            ptr = jnp.where(open_, ptr + 1, skip)
+            return ptr, force, z, steps + 1
+
+        init = (jnp.int32(0), jnp.zeros((2,), dtype), jnp.asarray(0.0, dtype), jnp.int32(0))
+        _, force, z, steps = jax.lax.while_loop(cond, body, init)
+        return force, z, steps
+
+    force, z, steps = jax.vmap(traverse)(pos, y_query)
+    return RepulsionResult(force=force, z_per_point=z, steps=steps)
+
+
 @functools.partial(jax.jit, static_argnames=())
 def bh_repulsion_sorted(
     y_sorted: jax.Array,
@@ -46,44 +106,8 @@ def bh_repulsion_sorted(
 ) -> RepulsionResult:
     """Barnes-Hut repulsion for points in Morton-sorted order."""
     n = y_sorted.shape[0]
-    dtype = y_sorted.dtype
-    theta2 = jnp.asarray(theta, dtype) ** 2
-    n_nodes = tree.n_nodes
-    cap = tree.capacity
-    is_leaf = tree.is_leaf
-
-    def traverse(p, yp):
-        def cond(state):
-            ptr, _, _, _ = state
-            return ptr < n_nodes
-
-        def body(state):
-            ptr, force, z, steps = state
-            k = jnp.minimum(ptr, cap - 1)
-            s = tree.start[k]
-            e = tree.end[k]
-            cnt = summary.count[k]
-            inside = (s <= p) & (p < e)
-            cnt_eff = cnt - jnp.where(inside, jnp.asarray(1.0, dtype), 0.0)
-            sum_eff = summary.sum_y[k] - jnp.where(inside, yp, jnp.zeros_like(yp))
-            com = sum_eff / jnp.maximum(cnt_eff, 1.0)
-            diff = yp - com
-            d2 = jnp.sum(diff * diff)
-            side = summary.side[k]
-            open_ = (~is_leaf[k]) & (side * side >= theta2 * d2)
-            w = jnp.where(open_, 0.0, cnt_eff)          # contribute iff accepted
-            q = 1.0 / (1.0 + d2)
-            z = z + w * q
-            force = force + (w * q * q) * diff
-            ptr = jnp.where(open_, ptr + 1, tree.skip[k])
-            return ptr, force, z, steps + 1
-
-        init = (jnp.int32(0), jnp.zeros((2,), dtype), jnp.asarray(0.0, dtype), jnp.int32(0))
-        _, force, z, steps = jax.lax.while_loop(cond, body, init)
-        return force, z, steps
-
-    force, z, steps = jax.vmap(traverse)(jnp.arange(n, dtype=jnp.int32), y_sorted)
-    return RepulsionResult(force=force, z_per_point=z, steps=steps)
+    return walk(node_table(tree, summary), tree.n_nodes, theta,
+                jnp.arange(n, dtype=jnp.int32), y_sorted)
 
 
 def bh_repulsion(y: jax.Array, codes: jax.Array, tree_builder, theta):

@@ -13,7 +13,7 @@ from repro.core import (
 )
 from repro.core import exact, similarity
 from repro.core.bsp import binary_search_perplexity as bsp_search
-from repro.core.repulsive import bh_repulsion_sorted
+from repro.core.repulsive import RepulsionResult, bh_repulsion_sorted
 from repro.core.tsne import TsneConfig, run_tsne
 
 
@@ -242,6 +242,95 @@ class TestRepulsive:
             rep = bh_repulsion_sorted(ys, tree, summ, 0.0)
             f[compress] = np.asarray(rep.force)
         np.testing.assert_allclose(f[True], f[False], rtol=1e-4, atol=1e-6)
+
+    @pytest.mark.parametrize("theta", [0.0, 0.5])
+    @pytest.mark.parametrize("case", ["clusters", "coincident", "duplicate_run"])
+    def test_packed_walk_matches_field_gathers(self, case, theta):
+        """The one-row walk does what a gather per node field did, exactly."""
+        y, _ = make_points(203, seed=29)             # N not a multiple of 8
+        if case == "coincident":
+            y = np.zeros((37, 2), np.float32)
+        elif case == "duplicate_run":
+            # identical points and points a cell apart at depth 16 share a
+            # code: max-depth leaves of many points, the query inside them
+            y[40:70] = y[40]
+            y[100:120] = y[100] + 1e-7 * np.arange(20, dtype=np.float32)[:, None]
+        yj = jnp.asarray(y)
+        cent, r = span_radius(yj)
+        cs, ys, _ = sort_points_by_code(yj, morton_encode(yj, cent, r))
+        tree = build_quadtree(cs)
+        summ = summarize(tree, ys, r)
+        if case == "duplicate_run":
+            leaf_counts = np.asarray(summ.count)[np.asarray(tree.is_leaf)]
+            assert leaf_counts.max() >= 20
+        got = bh_repulsion_sorted(ys, tree, summ, theta)
+        want = _field_gather_walk(ys, tree, summ, theta)
+        np.testing.assert_array_equal(np.asarray(got.steps), np.asarray(want.steps))
+        np.testing.assert_allclose(np.asarray(got.force), np.asarray(want.force),
+                                   rtol=1e-6)
+        np.testing.assert_allclose(np.asarray(got.z_per_point),
+                                   np.asarray(want.z_per_point), rtol=1e-6)
+
+    def test_node_table_int_columns_exact_above_2_24(self):
+        from repro.core.quadtree import LinearQuadtree
+        from repro.core.repulsive import node_table
+        from repro.core.summarize import TreeSummary
+
+        big = np.array([2**24 + 1, 2**30 + 3, 2**31 - 1, 0], np.int32)
+        assert int(np.float32(big[0])) != big[0]     # a converted float rounds
+        skip = np.array([1, 3, 3, 2**31 - 1], np.int32)  # nodes 0 and 2 leaves
+        tree = LinearQuadtree(start=jnp.asarray(big), end=jnp.asarray(big[::-1]),
+                              level=jnp.zeros(4, jnp.int32), skip=jnp.asarray(skip),
+                              n_nodes=jnp.int32(4), depth=16)
+        side = jnp.asarray([1.5, 2.0, 3.0, 0.25], jnp.float32)
+        summ = TreeSummary(count=jnp.arange(4, dtype=jnp.float32),
+                           sum_y=jnp.ones((4, 2), jnp.float32),
+                           com=jnp.ones((4, 2), jnp.float32), side=side)
+        table = np.asarray(node_table(tree, summ))
+        assert table.shape == (4, 8) and table.dtype == np.float32
+        ints = table[:, :3].view(np.int32)
+        np.testing.assert_array_equal(ints, np.stack([big, big[::-1], skip], 1))
+        np.testing.assert_array_equal(table[:, 6], [-np.inf, 4.0, -np.inf, 0.0625])
+
+
+def _field_gather_walk(y_sorted, tree, summary, theta):
+    """The walk as it read each node field with a gather of its own."""
+    n = y_sorted.shape[0]
+    dtype = y_sorted.dtype
+    theta2 = jnp.asarray(theta, dtype) ** 2
+    n_nodes, cap, is_leaf = tree.n_nodes, tree.capacity, tree.is_leaf
+
+    def traverse(p, yp):
+        def body(state):
+            ptr, force, z, steps = state
+            k = jnp.minimum(ptr, cap - 1)
+            s = tree.start[k]
+            e = tree.end[k]
+            cnt = summary.count[k]
+            inside = (s <= p) & (p < e)
+            cnt_eff = cnt - jnp.where(inside, jnp.asarray(1.0, dtype), 0.0)
+            sum_eff = summary.sum_y[k] - jnp.where(inside, yp, jnp.zeros_like(yp))
+            com = sum_eff / jnp.maximum(cnt_eff, 1.0)
+            diff = yp - com
+            d2 = jnp.sum(diff * diff)
+            side = summary.side[k]
+            open_ = (~is_leaf[k]) & (side * side >= theta2 * d2)
+            w = jnp.where(open_, 0.0, cnt_eff)
+            q = 1.0 / (1.0 + d2)
+            z = z + w * q
+            force = force + (w * q * q) * diff
+            ptr = jnp.where(open_, ptr + 1, tree.skip[k])
+            return ptr, force, z, steps + 1
+
+        init = (jnp.int32(0), jnp.zeros((2,), dtype), jnp.asarray(0.0, dtype),
+                jnp.int32(0))
+        _, force, z, steps = jax.lax.while_loop(lambda st: st[0] < n_nodes,
+                                                body, init)
+        return force, z, steps
+
+    force, z, steps = jax.jit(jax.vmap(traverse))(
+        jnp.arange(n, dtype=jnp.int32), y_sorted)
+    return RepulsionResult(force=force, z_per_point=z, steps=steps)
 
 
 # -------------------------------------------------------------- attractive --

@@ -116,3 +116,26 @@ def test_descent_step_layers_survive_the_v5e_compile(spec):
         [scopes.ATTRACTIVE, scopes.BH_TRAVERSAL, scopes.BH_TREE]
     for name in (scopes.BH_SUMMARIZE, scopes.UPDATE):
         assert f"/{name}/" in entry, name
+
+
+def test_bh_walk_gathers_one_node_row_per_turn(spec):
+    """Each turn of the compiled walk reads a node's record with one gather,
+    not one gather per field."""
+    import re
+
+    from repro.core.quadtree import LinearQuadtree
+    from repro.core.repulsive import bh_repulsion_sorted
+    from repro.core.summarize import TreeSummary
+
+    cap, i32 = 2 * N + 1, jnp.int32
+    tree = LinearQuadtree(start=spec((cap,), i32), end=spec((cap,), i32),
+                          level=spec((cap,), i32), skip=spec((cap,), i32),
+                          n_nodes=spec((), i32), depth=16)
+    summ = TreeSummary(count=spec((cap,)), sum_y=spec((cap, 2)),
+                       com=spec((cap, 2)), side=spec((cap,)))
+    text = bh_repulsion_sorted.lower(spec((N, 2)), tree, summ, spec(())) \
+        .compile().as_text()
+    in_body = [line for line in text.splitlines() if " gather(" in line
+               and "while/body/" in re.search(r'op_name="([^"]*)"', line).group(1)]
+    assert len(in_body) == 1, in_body
+    assert f"f32[{N},8]" in in_body[0]
