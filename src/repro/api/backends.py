@@ -25,7 +25,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import attractive, exact, scopes
-from repro.core.fft_repulsion import fft_repulsion
+from repro.core.fft_repulsion import fft_repulsion, lattice_extent
 from repro.core.tsne import (
     DEFAULT_ATTRACTIVE_IMPL, GradResult, NeighborGraph, TsneConfig, bh_gradient,
     combine_forces,
@@ -159,8 +159,11 @@ class FFTBackend:
                                       self.attractive_block)
         f_rep_unnorm, z = fft_repulsion(y, n_boxes=self.n_boxes,
                                         interp_impl=self.interp_impl)
+        with jax.named_scope(scopes.FFT_SPREAD):
+            # fft_repulsion's own reduction: XLA computes it once
+            _, span = lattice_extent(y)
         return combine_forces(f_attr, kl_attr, f_rep_unnorm, z, exaggeration,
-                              graph.p_logp)
+                              graph.p_logp, fft_span=span)
 
 
 # --------------------------------------------------------------------------

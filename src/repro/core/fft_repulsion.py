@@ -8,16 +8,24 @@ embedding), and interpolation back:
     phi_k(x_i) ~= sum_(p^2 nodes) L_p(x_i) * (K * spread(charges))[node]
 
 Charges {1, y_x, y_y} against K2 = (1+d^2)^-2 give the repulsive numerator;
-charge {1} against K1 = (1+d^2)^-1 gives Z.  O(N p^2 + M^2 log M) per
-iteration instead of O(N log N) BH traversal.  Accuracy is controlled by
-the node count (tests: ~1% force error at 128 nodes/dim vs exact O(N^2)).
+charge {1} against K1 = (1+d^2)^-1 gives Z, less each point's pair with
+itself as the lattice interpolates it (:func:`self_k1`).  O(N p^2 +
+M^2 log M) per iteration instead of O(N log N) BH traversal.  Accuracy is
+controlled by the node count (tests: ~1% force error at 128 nodes/dim vs
+exact O(N^2)).
 
 The interpolation scatter/gather — the O(N p^2) half, which dominates once
-N >> nodes^2 — is split into :func:`spread_to_grid` / :func:`gather_from_grid`
-so it can dispatch to the Pallas tile kernels in ``kernels/interp_kernel.py``
+N >> nodes^2 — is defined by :func:`spread_to_grid` / :func:`gather_from_grid`,
+a scatter-add and a gather of the 3x3 taps: the oracles that every
+implementation is tested against.  The XLA path (``interp_impl="xla"``) runs
+them as matmuls over one-hot tap matrices instead (:func:`spread_by_matmul`
+/ :func:`gather_by_matmul`): the TPU compiler unrolls a scatter or gather of
+N * 9 taps into code that grows with N (compiling the spread of 70,000
+points for a v5e took 110 s and gave 26 MB of code), where a matmul's code
+does not grow.  The Pallas tile kernels in ``kernels/interp_kernel.py``
 (``interp_impl="pallas"``; registered as ``fft_spread`` / ``fft_gather`` in
-the ``kernels/ops`` registry).  The jnp functions here are the oracles those
-kernels are parity-tested against.  The FFT itself stays in XLA.
+the ``kernels/ops`` registry) tile the same matmuls.  The FFT itself stays
+in XLA.
 """
 from __future__ import annotations
 
@@ -50,15 +58,26 @@ def _lagrange_weights(frac: jax.Array) -> jax.Array:
     return jnp.stack([w0, w1, w2], axis=-1)  # [N, 3]
 
 
+def lattice_extent(y: jax.Array):
+    """The lattice's lower corner [2] and its side ``span`` in embedding
+    units: the points' bounding box, 1e-4 wider on each side, squared up.
+
+    FIt-SNE sizes its grid from ``span`` (max(50, span) intervals a
+    dimension); here the grid is fixed at ``n_boxes``, and ``span`` is
+    reported so a fit shows whether that rule would have grown it.
+    """
+    lo = jnp.min(y, axis=0) - 1e-4
+    hi = jnp.max(y, axis=0) + 1e-4
+    return lo, jnp.maximum(jnp.max(hi - lo), 1e-12)
+
+
 def interp_coords(y: jax.Array, n_boxes: int):
     """Lattice geometry shared by spread and gather.
 
     Returns (base [N,2] int32 — the box-start node per dim, wx [N,3],
     wy [N,3] — per-dim Lagrange weights, h — node spacing).
     """
-    lo = jnp.min(y, axis=0) - 1e-4
-    hi = jnp.max(y, axis=0) + 1e-4
-    span = jnp.maximum(jnp.max(hi - lo), 1e-12)
+    lo, span = lattice_extent(y)
     m = n_boxes * (P_ORDER - 1)            # interior lattice nodes per dim
     h = span / m
     u = (y - lo[None, :]) / h              # fractional lattice coords in [0, m)
@@ -68,6 +87,21 @@ def interp_coords(y: jax.Array, n_boxes: int):
     wx = _lagrange_weights(frac[:, 0])
     wy = _lagrange_weights(frac[:, 1])
     return base, wx, wy, h
+
+
+def self_k1(wx, wy, h):
+    """[N]: each point's K1 with itself as the lattice interpolates it, the
+    point's 3 x 3 taps against each other:
+    sum w_a w_b w_c w_d / (1 + h^2 ((a - c)^2 + (b - d)^2)).  Z leaves
+    these out, where leaving out 1 a point would leave the interpolation's
+    error on the N self-pairs in Z."""
+    t = jnp.arange(P_ORDER, dtype=wx.dtype)
+    d2 = (t[:, None] - t[None, :]) ** 2 * (h * h)                  # [a, c]
+    k1 = 1.0 / (1.0 + d2[:, None, :, None] + d2[None, :, None, :])  # [a,b,c,d]
+    wxx = wx[:, :, None] * wx[:, None, :]                           # [N, a, c]
+    wyy = wy[:, :, None] * wy[:, None, :]                           # [N, b, d]
+    return jnp.sum(wxx[:, :, None, :, None] * wyy[:, None, :, None, :]
+                   * k1[None], axis=(1, 2, 3, 4))
 
 
 def spread_to_grid(base, wx, wy, charges, nodes: int):
@@ -104,13 +138,41 @@ def gather_from_grid(pot, base, wx, wy):
     return jnp.sum(vals * w2d[:, :, None], axis=1)          # [N,C]
 
 
+def _taps(base, w, nodes: int):
+    """[N, nodes]: each point's 3 weights at its box's nodes base..base+2
+    along one dimension, 0 elsewhere."""
+    off = jnp.arange(nodes)[None, :] - base[:, None]
+    return sum(jnp.where(off == t, w[:, t, None], 0.0) for t in range(P_ORDER))
+
+
+def spread_by_matmul(base, wx, wy, charges, nodes: int):
+    """:func:`spread_to_grid` as one matmul: grid[a, b, c] =
+    sum_i Tx[i, a] * Ty[i, b] * charges[i, c], with T the one-hot taps."""
+    n, c = charges.shape
+    tx = _taps(base[:, 0], wx, nodes)
+    tyc = _taps(base[:, 1], wy, nodes)[:, :, None] * charges[:, None, :]
+    grid = jnp.dot(tx.T, tyc.reshape(n, nodes * c),
+                   precision=jax.lax.Precision.HIGHEST)
+    return grid.reshape(nodes, nodes, c)
+
+
+def gather_by_matmul(pot, base, wx, wy):
+    """:func:`gather_from_grid` as one matmul: phi[i, c] =
+    sum_b Ty[i, b] * (Tx @ pot)[i, b, c], with T the one-hot taps."""
+    nodes, _, c = pot.shape
+    n = base.shape[0]
+    rows = jnp.dot(_taps(base[:, 0], wx, nodes), pot.reshape(nodes, nodes * c),
+                   precision=jax.lax.Precision.HIGHEST).reshape(n, nodes, c)
+    return jnp.sum(_taps(base[:, 1], wy, nodes)[:, :, None] * rows, axis=1)
+
+
 @functools.partial(jax.jit, static_argnames=("n_boxes", "interp_impl"))
 def fft_repulsion(y: jax.Array, n_boxes: int = 48, interp_impl: str = "xla"):
     """Returns (force_unnorm [N,2], z) matching exact_repulsion's contract.
 
-    ``interp_impl`` selects the spread/gather implementation: "xla" (the jnp
-    oracles above) or "pallas" (tiled one-hot-matmul kernels, interpret-mode
-    on CPU).
+    ``interp_impl`` selects the spread/gather implementation: "xla" (the
+    one-hot matmuls above) or "pallas" (the same matmuls in tiled kernels,
+    interpret-mode on CPU).
     """
     if not 1 <= n_boxes <= MAX_N_BOXES:
         raise ValueError(
@@ -121,7 +183,7 @@ def fft_repulsion(y: jax.Array, n_boxes: int = 48, interp_impl: str = "xla"):
         from repro.kernels.ops import fft_gather, fft_spread
         spread, gather = fft_spread, fft_gather
     elif interp_impl == "xla":
-        spread, gather = spread_to_grid, gather_from_grid
+        spread, gather = spread_by_matmul, gather_by_matmul
     else:
         raise ValueError(
             f"unknown interp impl {interp_impl!r} "
@@ -160,7 +222,7 @@ def fft_repulsion(y: jax.Array, n_boxes: int = 48, interp_impl: str = "xla"):
         phi = gather(pot_all, base, wx, wy)                # [N, 4]
         phi2_1, phi2_x, phi2_y, phi1_1 = (phi[:, 0], phi[:, 1], phi[:, 2],
                                           phi[:, 3])
-        z = jnp.sum(phi1_1) - n                            # remove self terms
+        z = jnp.sum(phi1_1) - jnp.sum(self_k1(wx, wy, h))  # self-pairs out
         fx = y[:, 0] * phi2_1 - phi2_x                     # self term cancels
         fy = y[:, 1] * phi2_1 - phi2_y
         return jnp.stack([fx, fy], axis=1), jnp.maximum(z, 1e-30)

@@ -167,6 +167,8 @@ class GradResult(NamedTuple):
     # max_traversal it is the share of the lockstep walk's lane-turns that
     # did work.
     mean_traversal: jax.Array | float = 0.0
+    # FFT interpolation lattice's side in embedding units; 0 for the others
+    fft_span: jax.Array | float = 0.0
 
 
 @jax.tree_util.register_dataclass
@@ -194,7 +196,7 @@ class NeighborGraph:
 
 def combine_forces(
     f_attr, kl_attr, f_rep_unnorm, z, exaggeration, p_logp,
-    max_traversal=None, mean_traversal=None,
+    max_traversal=None, mean_traversal=None, fft_span=None,
 ) -> GradResult:
     """Shared backend epilogue (eq. 6/7): fold attractive + repulsive terms.
 
@@ -211,8 +213,10 @@ def combine_forces(
         max_traversal = jnp.zeros((), jnp.int32)
     if mean_traversal is None:
         mean_traversal = jnp.zeros((), dtype)
+    if fft_span is None:
+        fft_span = jnp.zeros((), dtype)
     return GradResult(grad=grad, kl=kl, z=z, max_traversal=max_traversal,
-                      mean_traversal=mean_traversal)
+                      mean_traversal=mean_traversal, fft_span=fft_span)
 
 
 # ---------------------------------------------------------------------------
@@ -295,6 +299,7 @@ class StepStats(NamedTuple):
     z: jax.Array
     max_traversal: jax.Array
     mean_traversal: jax.Array | float = 0.0
+    fft_span: jax.Array | float = 0.0
 
 
 @functools.partial(jax.jit, static_argnames=("backend", "lr", "min_gain"))
@@ -325,7 +330,8 @@ def tsne_step(
         new_state = gd_update(state, res.grad, lr, momentum, min_gain)
     return new_state, StepStats(kl=res.kl, grad_norm=grad_norm, z=res.z,
                                 max_traversal=res.max_traversal,
-                                mean_traversal=res.mean_traversal)
+                                mean_traversal=res.mean_traversal,
+                                fft_span=res.fft_span)
 
 
 # ---------------------------------------------------------------------------
@@ -355,6 +361,7 @@ class IterationStats:
     momentum: float
     elapsed_s: float        # wall time since gradient descent started
     mean_traversal: float = 0.0  # mean BH tree walk over points (0 likewise)
+    fft_span: float = 0.0   # FFT lattice's side, embedding units (0 likewise)
 
 
 ObserverFn = Callable[[IterationStats], None]
@@ -509,9 +516,10 @@ def run_tsne(
     phase's time.  Besides them it lists the Barnes-Hut walk at each
     checkpoint, from the checkpoint's stats and not from a span
     (``max_traversal``: the lockstep walk's turns; ``mean_traversal``: the
-    mean over points; 0 without a tree).  Checkpoint stats also land on ``metrics`` (default global
-    registry) as ``fit.grad_norm`` / ``fit.gain_mean`` histograms and a
-    ``fit.kl`` gauge.
+    mean over points; 0 without a tree), and the FFT lattice's side
+    (``fft_span``, embedding units; 0 for the other backends).  Checkpoint
+    stats also land on ``metrics`` (default global registry) as
+    ``fit.grad_norm`` / ``fit.gain_mean`` histograms and a ``fit.kl`` gauge.
     """
     x = jnp.asarray(x, config.dtype)
     n = x.shape[0]
@@ -538,6 +546,7 @@ def run_tsne(
         kl_hist = []
         max_walk: list[int] = []
         mean_walk: list[float] = []
+        spans: list[float] = []
         kl = float("nan")
         it = 0
         converged = False
@@ -570,6 +579,7 @@ def run_tsne(
                             kl_hist.append((it + 1, kl))
                             max_walk.append(int(got.max_traversal))
                             mean_walk.append(float(got.mean_traversal))
+                            spans.append(float(got.fft_span))
                             metrics.histogram("fit.grad_norm").observe(
                                 grad_norm)
                             metrics.gauge("fit.kl").set(kl)
@@ -589,6 +599,7 @@ def run_tsne(
                                     exaggeration=exag, momentum=mom,
                                     elapsed_s=time.perf_counter() - sp_gd.t0,
                                     mean_traversal=mean_walk[-1],
+                                    fft_span=spans[-1],
                                 ))
                         if grad_norm < config.min_grad_norm:
                             converged = True
@@ -598,6 +609,7 @@ def run_tsne(
         timings["gradient_descent"] = sp_gd.duration_s
         timings["max_traversal"] = max_walk
         timings["mean_traversal"] = mean_walk
+        timings["fft_span"] = spans
         metrics.counter("fit.iterations").inc(it + 1)
     return TsneResult(
         y=np.asarray(state.y),

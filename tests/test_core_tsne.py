@@ -576,3 +576,23 @@ class TestStepLayers:
                    for s in seen["barnes_hut"])
         assert all(s.max_traversal == 0 and s.mean_traversal == 0
                    for s in seen["fft"])
+
+    def test_checkpoints_report_the_fft_span(self):
+        from repro.api import TSNE
+
+        x, _ = make_points(150, seed=7, dim=8)
+        spans = {}
+        for method in ("barnes_hut", "fft"):
+            stats = []
+            est = TSNE(method=method, perplexity=8.0, n_iter=40, kl_every=10,
+                       random_state=0, backend_options={"fft_n_boxes": 8},
+                       callbacks=(stats.append,)).fit(x)
+            assert est.timings_["fft_span"] == [s.fft_span for s in stats]
+            spans[method] = est.timings_["fft_span"]
+        assert spans["barnes_hut"] == [0.0] * 4
+        # the lattice's side: the embedding's widest extent, which early
+        # exaggeration spreads out
+        fft = spans["fft"]
+        assert len(fft) == 4 and 0 < fft[0] < fft[-1]
+        assert fft[-1] == pytest.approx(
+            float(np.ptp(est.embedding_, axis=0).max()), rel=0.2)

@@ -229,12 +229,13 @@ class TestTracedFit:
         assert set(est.timings_) == {
             "knn", "bsp", "symmetrize", "gradient_descent",
             "neighbor_method", "n_neighbors", "bsp_impl", "chunk_size",
-            "knn_mean_d2", "max_traversal", "mean_traversal"}
+            "knn_mean_d2", "max_traversal", "mean_traversal", "fft_span"}
         d = est.tracer_.durations()
         assert est.timings_["gradient_descent"] == pytest.approx(
             d["early_exaggeration"], rel=0.05)
         assert len(est.timings_["max_traversal"]) == 2
         assert len(est.timings_["mean_traversal"]) == 2
+        assert len(est.timings_["fft_span"]) == 2
 
     def test_chrome_trace_written_and_loadable(self, traced_fit):
         _, path = traced_fit

@@ -139,3 +139,32 @@ def test_bh_walk_gathers_one_node_row_per_turn(spec):
                and "while/body/" in re.search(r'op_name="([^"]*)"', line).group(1)]
     assert len(in_body) == 1, in_body
     assert f"f32[{N},8]" in in_body[0]
+
+
+def test_fft_step_interpolates_by_matmul(spec):
+    """The FFT step at the mnist-fft cell's shapes spreads and gathers by
+    matmul: no scatter or gather of the N * 9 taps, which the v5e compiler
+    unrolls into code that grows with N."""
+    import re
+
+    from repro.api.backends import make_backend
+    from repro.core import scopes
+    from repro.core.tsne import NeighborGraph, TsneConfig, TsneState, tsne_step
+
+    i32 = jnp.int32
+    state = TsneState(y=spec((N, 2)), velocity=spec((N, 2)),
+                      gains=spec((N, 2)), iteration=spec((), i32))
+    graph = NeighborGraph(
+        p_cols=spec((N, W), i32), p_vals=spec((N, W)), edge_src=spec((1,), i32),
+        edge_dst=spec((1,), i32), edge_w=spec((1,)), p_logp=spec(()), n=N)
+    cfg = TsneConfig(method="fft", fft_n_boxes=50)
+    text = tsne_step.lower(
+        state, graph, spec(()), spec(()),
+        backend=make_backend("fft", cfg, N), lr=5833.33, min_gain=0.01,
+    ).compile().as_text()
+    interp = (scopes.FFT_SPREAD, scopes.FFT_GATHER)
+    ops = [(m.group(1), line) for line in text.splitlines()
+           if (m := re.search(r' (scatter|gather|dot|convolution)\(', line))
+           and any(f"/{s}/" in line for s in interp)]
+    assert not [line for op, line in ops if op in ("scatter", "gather")]
+    assert {op for op, _ in ops} & {"dot", "convolution"}
