@@ -69,12 +69,9 @@ def _attractive(y, graph: NeighborGraph, attractive_impl: str,
                 "this NeighborGraph was preprocessed edges-only "
                 "(attractive_impl='edges')"
             )
-        if attractive_impl == "blocked":
-            return attractive.attractive_forces_ell_blocked(
-                y, graph.p_cols, graph.p_vals, block=attractive_block
-            )
-        return attractive.ell_impl(attractive_impl)(y, graph.p_cols,
-                                                    graph.p_vals)
+        return attractive.ell_forces(y, graph.p_cols, graph.p_vals,
+                                     attractive_impl, attractive_block,
+                                     graph.buckets)
 
 
 # --------------------------------------------------------------------------
@@ -136,6 +133,7 @@ class BarnesHutBackend:
             compress_tree=self.compress_tree, use_pallas=self.use_pallas,
             attractive_impl=self.attractive_impl,
             attractive_block=self.attractive_block,
+            buckets=graph.buckets,
         )
 
 

@@ -17,7 +17,7 @@ import numpy as np
 from repro import obs
 from repro.core.tsne import (
     IterationStats, NeighborGraph, ObserverFn, TsneConfig, TsneResult,
-    run_tsne,
+    attractive_layout, run_tsne,
 )
 from repro.api.backends import GradientBackend, make_backend
 
@@ -379,11 +379,16 @@ class TSNE:
         est.timings_ = None         # loaded, not fitted here: no phase ran
         est._query_index = None
         if "graph_p_cols" in z.files:
+            n = est._x_fit.shape[0]
+            p_cols, p_vals = z["graph_p_cols"], z["graph_p_vals"]
             est.neighbor_graph_ = NeighborGraph(
-                p_cols=z["graph_p_cols"], p_vals=z["graph_p_vals"],
+                p_cols=p_cols, p_vals=p_vals,
                 edge_src=z["graph_edge_src"], edge_dst=z["graph_edge_dst"],
                 edge_w=z["graph_edge_w"], p_logp=float(z["graph_p_logp"]),
-                n=est._x_fit.shape[0], has_edges=bool(z["graph_has_edges"]),
+                n=n, has_edges=bool(z["graph_has_edges"]),
+                # the attractive loop's layout is rebuilt, not stored
+                buckets=attractive_layout(p_cols, p_vals,
+                                          est._build_config(n)),
             )
         else:
             est.neighbor_graph_ = None

@@ -9,6 +9,17 @@ adds software prefetch for the pseudo-random y_j reads.  On TPU:
   Pallas double-buffering plays the role of software prefetch);
 * the 10-FLOP epilogue is `kernels/attractive_kernel.py` when enabled.
 
+On the chip the cost of the ELL forms is one scalar gather issue per
+column (two per entry, x and y), whatever the bytes: the layer runs far
+below the HBM roofline, so what it costs is the count of gathered columns.
+``symmetrize_ell`` pads every row to the graph's largest degree W, which is
+three times the mean on MNIST.  ``attractive_forces_bucketed`` (the default
+'blocked' path whenever the graph carries degree buckets) gathers each row
+only up to its bucket's width; ``attractive_forces_ell_blocked``, a loop over
+512-row blocks of the whole width, is its oracle and the path of a graph
+without buckets.  A loop bounds each gather's size: the v5e compiler
+unrolls a single very large gather into code that grows with it.
+
 Two equivalent formulations are provided:
 
 ``attractive_forces_ell``   — Algorithm 2 verbatim over a symmetric ELL matrix
@@ -66,17 +77,35 @@ def attractive_forces_ell_components(y: jax.Array, cols: jax.Array, vals: jax.Ar
     return jnp.stack([fx, fy], axis=1), kl_attr
 
 
+def _block_terms(yx, yy, x0, y0, cb, vb, width: int | None = None):
+    """One row block of Algorithm 2: rows at (x0, y0) [R], their columns
+    ``cb`` [R, w] with values ``vb``; the rows' forces and their KL part.
+
+    With ``width`` (> w) each row's force terms are summed as a row of that
+    width whose entries past w are zeros: the padding a truncated row left
+    out, put back in the sum and not in the gather.
+    """
+    gx = yx[cb]
+    gy = yy[cb]
+    dx = x0[:, None] - gx
+    dy = y0[:, None] - gy
+    d2 = dx * dx + dy * dy
+    pq = vb / (1.0 + d2)
+    tx, ty = pq * dx, pq * dy
+    if width is not None and width > cb.shape[1]:
+        pad = ((0, 0), (0, width - cb.shape[1]))
+        tx, ty = jnp.pad(tx, pad), jnp.pad(ty, pad)
+    return jnp.sum(tx, 1), jnp.sum(ty, 1), jnp.sum(vb * jnp.log1p(d2))
+
+
 def attractive_forces_ell_blocked(y: jax.Array, cols: jax.Array, vals: jax.Array,
                                   block: int = 512):
-    """Algorithm 2, cache-blocked (§Perf hillclimb — the winning variant).
+    """Algorithm 2 as a loop over ``block``-row blocks of the [N, W] ELL.
 
-    The fully vectorized forms materialize [N, W] planes (tens of MB at
-    N=20k, W=90) that thrash L2; the per-row loop has a tiny working set but
-    no lane batching.  Blocking rows at `block` keeps the gather working set
-    (~block*W floats) cache-resident while every op inside the block stays
-    vectorized — the same SIMD+locality combination as the paper's AVX-512 +
-    prefetch attractive kernel.  Measured 4.7x over the unblocked vector
-    form and 2.3x over the row loop at N=20k (EXPERIMENTS.md §Perf).
+    Each turn gathers ``block * W`` columns, so the live transients are
+    bounded by the block and not by N.  Every row gathers the whole width
+    W, padding included: the oracle of :func:`attractive_forces_bucketed`,
+    and the path of a graph built without degree buckets.
     """
     n, w = cols.shape
     pad = (-n) % block
@@ -89,18 +118,43 @@ def attractive_forces_ell_blocked(y: jax.Array, cols: jax.Array, vals: jax.Array
 
     def one(args):
         cb, vb, x0, y0 = args
-        gx = yx[cb]
-        gy = yy[cb]
-        dx = x0[:, None] - gx
-        dy = y0[:, None] - gy
-        d2 = dx * dx + dy * dy
-        pq = vb / (1.0 + d2)
-        return jnp.sum(pq * dx, 1), jnp.sum(pq * dy, 1), jnp.sum(vb * jnp.log1p(d2))
+        return _block_terms(yx, yy, x0, y0, cb, vb)
 
     shape = lambda a: a.reshape(nb, block, *a.shape[1:])
     fx, fy, kl = jax.lax.map(one, (shape(cols_p), shape(vals_p), shape(x0_p), shape(y0_p)))
     force = jnp.stack([fx.reshape(-1)[:n], fy.reshape(-1)[:n]], axis=1)
     return force, jnp.sum(kl)
+
+
+def attractive_forces_bucketed(y: jax.Array, buckets):
+    """Algorithm 2 over the ELL's degree buckets (``similarity.degree_buckets``).
+
+    Each bucket is a loop over its turns of [R, w] rows: a turn gathers its
+    rows' own points and columns only up to the bucket's width w, so a row
+    gathers at most its degree rounded up the ladder, not the graph's
+    largest degree.  The per-entry math is :func:`attractive_forces_ell_blocked`'s
+    over the same entries in the same order, less padding's zero terms.
+    Each row's force is still summed over the ELL's whole width W, with
+    zeros for the padding, so it rounds as the whole-ELL loop's does: where
+    a backend's row sum does not depend on the block's row count, the
+    forces are that loop's to the bit, and a fit's descent does not move.
+    Forces come back to point order by one gather through ``buckets.inv``.
+    """
+    yx, yy = y[:, 0], y[:, 1]
+    width = max(c.shape[-1] for c in buckets.cols)      # the ELL's W
+
+    def one(args):
+        rb, cb, vb = args
+        return _block_terms(yx, yy, yx[rb], yy[rb], cb, vb, width)
+
+    fx, fy, kl = [], [], 0.0
+    for rows, cols, vals in zip(buckets.rows, buckets.cols, buckets.vals):
+        bx, by, bk = jax.lax.map(one, (rows, cols, vals))
+        fx.append(bx.reshape(-1))
+        fy.append(by.reshape(-1))
+        kl = kl + jnp.sum(bk)
+    force = jnp.stack([jnp.concatenate(fx), jnp.concatenate(fy)], axis=1)
+    return force[buckets.inv], kl
 
 
 # Single dispatch table for the ELL-layout variants — shared by bh_gradient
@@ -121,6 +175,17 @@ def ell_impl(name: str):
             f"unknown attractive_impl {name!r}; ELL variants: "
             f"{', '.join(sorted(ELL_IMPLS))} (or 'edges' with an edge list)"
         ) from None
+
+
+def ell_forces(y: jax.Array, cols: jax.Array, vals: jax.Array, impl: str,
+               block: int = 512, buckets=None):
+    """The ELL attractive term by ``impl`` name; 'blocked' runs over the
+    graph's degree buckets when it carries them."""
+    if impl == "blocked" and buckets is not None:
+        return attractive_forces_bucketed(y, buckets)
+    if impl == "blocked":
+        return attractive_forces_ell_blocked(y, cols, vals, block=block)
+    return ell_impl(impl)(y, cols, vals)
 
 
 def attractive_forces_frozen(y: jax.Array, nbr_y: jax.Array, p: jax.Array):

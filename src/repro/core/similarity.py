@@ -11,9 +11,17 @@ directed KNN neighborhoods in two interchangeable layouts:
   applied to both endpoints by attractive_forces_edges, so the symmetric
   sum over ordered pairs is recovered without materializing it.  Used by
   the fully jitted / distributed path; numerically identical forces.
+
+``degree_buckets`` cuts the ELL's rows into a few buckets by degree, so the
+attractive loop gathers only up to each row's own bucket's width instead of
+the graph's largest degree W.
 """
 from __future__ import annotations
 
+import dataclasses
+import math
+
+import jax
 import numpy as np
 import jax.numpy as jnp
 
@@ -156,6 +164,104 @@ def symmetrize_ell_chunked(cols, cond_p, chunk_size: int):
         sym_cols[row, rank] = col
         sym_vals[row, rank] = val / (2.0 * n)
     return sym_cols, sym_vals
+
+
+def _ceil_div(a: int, b: int) -> int:
+    return -(-a // b)
+
+
+def _round_up(x: int, m: int) -> int:
+    return _ceil_div(x, m) * m
+
+
+@jax.tree_util.register_dataclass
+@dataclasses.dataclass(frozen=True)
+class DegreeBuckets:
+    """The ELL's rows cut by degree (:func:`degree_buckets`).
+
+    Bucket b holds ``rows[b]`` [T, R] (the point of each row, T turns of R
+    rows), ``cols[b]`` / ``vals[b]`` [T, R, w] (those ELL rows truncated to
+    the bucket's width w).  ``inv`` [N] is each point's place in the
+    buckets' rows laid end to end.  Rows past a bucket's last point are
+    padding: point 0, col 0, val 0.
+    """
+    rows: tuple
+    cols: tuple
+    vals: tuple
+    inv: jax.Array
+
+    @property
+    def slots(self) -> int:
+        """Columns the attractive loop gathers per coordinate."""
+        return sum(math.prod(c.shape) for c in self.cols)
+
+
+# a bucket's width over the next's: about 8 buckets on MNIST's K = 91 graph
+LADDER_RATIO = 1.25
+
+
+def degree_buckets(cols, vals, block: int = 512,
+                   max_rows: int | None = None) -> DegreeBuckets:
+    """Cut the [N, W] ELL's rows into buckets on a ladder of widths.
+
+    A row's degree is one past its last real entry (padding, ``col ==
+    row``, follows the real entries).  The ladder descends from W by
+    ``LADDER_RATIO``, each width rounded up to a multiple of 8 (and at least 8
+    below the last), down to the smallest degree; each row goes to the
+    narrowest width that holds it, so uniform degrees give one bucket, the
+    ELL itself.  Rows are sorted by degree, descending (ties in point
+    order), and each keeps its entries in their order.
+
+    A bucket of width w runs in turns of at most ``block * W // w`` rows
+    (about the indices of one ``block``-row turn over the whole ELL) and at
+    most ``max_rows``; its rows are split evenly over as few turns as that
+    allows, each turn's rows rounded up to a multiple of 8.
+    """
+    cols = np.asarray(cols)
+    vals = np.asarray(vals)
+    n, w_max = cols.shape
+    real = cols != np.arange(n, dtype=cols.dtype)[:, None]
+    degree = np.where(real.any(axis=1),
+                      w_max - np.argmax(real[:, ::-1], axis=1), 0)
+    widths = [w_max]
+    low = max(int(degree.min()), 1)
+    while widths[-1] > 8:
+        w = min(_round_up(math.ceil(widths[-1] / LADDER_RATIO), 8),
+                widths[-1] - 8)
+        if w < low:
+            break
+        widths.append(w)
+    order = np.argsort(-degree, kind="stable")
+    # bucket b: widths[b + 1] < degree <= widths[b]
+    cut = np.searchsorted(-degree[order], -np.asarray(widths[1:]), side="left")
+    bounds = [0, *cut.tolist(), n]
+    out_rows, out_cols, out_vals = [], [], []
+    inv = np.empty(n, np.int32)
+    offset = 0
+    for w, lo, hi in zip(widths, bounds[:-1], bounds[1:]):
+        if hi == lo:
+            continue
+        cap = block * w_max // w
+        cap = cap // 8 * 8 or cap
+        if max_rows is not None:
+            cap = min(cap, max_rows)
+        cap = max(cap, 1)
+        turns = _ceil_div(hi - lo, cap)
+        r = min(_round_up(_ceil_div(hi - lo, turns), 8), cap)
+        pts = order[lo:hi]
+        rows = np.zeros(turns * r, np.int32)
+        rows[:hi - lo] = pts
+        bc = np.zeros((turns * r, w), cols.dtype)
+        bv = np.zeros((turns * r, w), vals.dtype)
+        bc[:hi - lo] = cols[pts, :w]
+        bv[:hi - lo] = vals[pts, :w]
+        inv[pts] = offset + np.arange(hi - lo, dtype=np.int32)
+        offset += turns * r
+        out_rows.append(rows.reshape(turns, r))
+        out_cols.append(bc.reshape(turns, r, w))
+        out_vals.append(bv.reshape(turns, r, w))
+    return DegreeBuckets(rows=tuple(out_rows), cols=tuple(out_cols),
+                         vals=tuple(out_vals), inv=inv)
 
 
 def dense_p_matrix(cols, cond_p):

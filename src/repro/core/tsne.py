@@ -32,9 +32,11 @@ from repro.core.repulsive import bh_repulsion_sorted
 # ``recompiles.tsne_step`` instead of being invisible.
 TSNE_STEP_RETRACES = obs.RecompileProbe("tsne_step")
 
-# Single source of truth for the attractive-kernel variant ('blocked' is the
-# cache-blocked Alg. 2 — the measured §Perf winner).  TsneConfig, bh_gradient
-# and the barnes_hut backend all default to this constant.
+# Single source of truth for the attractive-kernel variant.  'blocked' loops
+# over row blocks, so each gather stays a turn's size: over the graph's
+# degree buckets when it carries them (each row gathers only up to its
+# bucket's width), else over 512-row blocks of the whole ELL.  TsneConfig,
+# bh_gradient and the backends all default to this constant.
 DEFAULT_ATTRACTIVE_IMPL = "blocked"
 
 # Hard cap on the resolved neighbor width K.  The ELL layouts and the Pallas
@@ -84,7 +86,7 @@ class TsneConfig:
     # FFT-repulsion spread/gather implementation, same semantics
     # (core/fft_repulsion.py dispatch, used by the 'fft' backend)
     fft_interp_impl: str = "auto"
-    # 'blocked' (cache-blocked Alg.2 — default, §Perf winner) | 'ell'
+    # 'blocked' (row-block loop, over degree buckets — default) | 'ell'
     # (plain vectorized) | 'components' (SoA planes) | 'edges' (scatter)
     attractive_impl: str = DEFAULT_ATTRACTIVE_IMPL
     compress_tree: bool = True            # False = daal4py-like uncompressed tree
@@ -131,7 +133,7 @@ class TsneConfig:
     def resolve_attractive_block(self) -> int:
         """Gradient-side attractive row block: never exceeds the configured
         preprocessing chunk, so one knob bounds live transients end-to-end
-        (512 is the measured cache-resident default)."""
+        (512 rows of the whole ELL width a turn by default)."""
         if self.chunk_size is not None:
             return max(1, min(512, int(self.chunk_size)))
         return 512
@@ -188,6 +190,9 @@ class NeighborGraph:
     p_logp: jax.Array       # exact sum_ij p_ij log p_ij (KL constant)
     n: int = dataclasses.field(metadata=dict(static=True), default=0)
     has_edges: bool = dataclasses.field(metadata=dict(static=True), default=False)
+    # the ELL's rows cut by degree for the 'blocked' attractive loop
+    # (attractive_layout); None: that loop runs over the whole ELL
+    buckets: similarity.DegreeBuckets | None = None
 
     @property
     def edges(self) -> tuple[jax.Array, jax.Array, jax.Array] | None:
@@ -236,6 +241,7 @@ def bh_gradient(
     use_pallas: bool = False,
     attractive_impl: str = DEFAULT_ATTRACTIVE_IMPL,
     attractive_block: int = 512,
+    buckets: similarity.DegreeBuckets | None = None,
 ) -> GradResult:
     # --- quadtree building (step 3) ---
     with jax.named_scope(scopes.BH_TREE):
@@ -262,17 +268,12 @@ def bh_gradient(
     with jax.named_scope(scopes.ATTRACTIVE):
         if edges is not None:
             f_attr, kl_attr = attractive.attractive_forces_edges(y, *edges)
-        else:
-            if use_pallas:
-                from repro.kernels.ops import attractive_forces_ell as attr_ell
-            elif attractive_impl == "blocked":
-                attr_ell = functools.partial(
-                    attractive.attractive_forces_ell_blocked,
-                    block=attractive_block,
-                )
-            else:
-                attr_ell = attractive.ell_impl(attractive_impl)
+        elif use_pallas:
+            from repro.kernels.ops import attractive_forces_ell as attr_ell
             f_attr, kl_attr = attr_ell(y, p_cols, p_vals)
+        else:
+            f_attr, kl_attr = attractive.ell_forces(
+                y, p_cols, p_vals, attractive_impl, attractive_block, buckets)
     return combine_forces(f_attr, kl_attr, f_rep, z, exaggeration, p_logp,
                           max_traversal=max_traversal,
                           mean_traversal=mean_traversal)
@@ -441,6 +442,8 @@ def preprocess(
             has_edges = True
             p_cols = jnp.zeros((1, 1), jnp.int32)
             p_vals = jnp.zeros((1, 1), config.dtype)
+            buckets = None
+            fill = {}
         else:
             if chunk is not None:
                 sym_cols, sym_vals = similarity.symmetrize_ell_chunked(
@@ -454,16 +457,27 @@ def preprocess(
             src = dst = jnp.zeros((1,), jnp.int32)
             w = jnp.zeros((1,), config.dtype)
             has_edges = False
+            vals = np.asarray(sym_vals, np.dtype(config.dtype))
             p_cols = jnp.asarray(sym_cols)
-            p_vals = jnp.asarray(sym_vals, config.dtype)
+            p_vals = jnp.asarray(vals)
+            buckets = attractive_layout(sym_cols, vals, config)
+            # the attractive loop's gathered columns, and the share of them
+            # that are real entries (the rest is padding)
+            slots = sym_cols.size if buckets is None else buckets.slots
+            real = np.count_nonzero(sym_cols != np.arange(n)[:, None])
+            fill = dict(attractive_fill=real / slots, attractive_slots=slots,
+                        attractive_buckets=1 if buckets is None
+                        else len(buckets.cols))
+            sp_sym.annotate(**fill)
         graph = NeighborGraph(
             p_cols=p_cols, p_vals=p_vals,
             edge_src=src, edge_dst=dst, edge_w=w,
             p_logp=jnp.asarray(p_logp, config.dtype),
             n=n,
             has_edges=has_edges,
+            buckets=buckets,
         )
-        sp_sym.sync((graph.p_vals, graph.edge_w))
+        sp_sym.sync((graph.p_vals, graph.edge_w, graph.buckets))
     return graph, dict(
         knn=sp_knn.duration_s, bsp=sp_bsp.duration_s,
         symmetrize=sp_sym.duration_s,
@@ -471,7 +485,20 @@ def preprocess(
         bsp_impl=bsp_impl,
         chunk_size=chunk,
         knn_mean_d2=float(jnp.mean(d2)),
+        **fill,
     )
+
+
+def attractive_layout(p_cols, p_vals, config: TsneConfig):
+    """The degree buckets the 'blocked' attractive loop runs over, on the
+    device (None for the other layouts): the host ELL's rows cut by degree
+    (``similarity.degree_buckets``), each turn within the configured row
+    block's indices and the preprocessing chunk's rows."""
+    if config.attractive_impl != "blocked":
+        return None
+    return jax.tree.map(jnp.asarray, similarity.degree_buckets(
+        p_cols, p_vals, block=config.resolve_attractive_block(),
+        max_rows=config.resolve_chunk_size(len(p_cols))))
 
 
 def init_state(n: int, config: TsneConfig) -> TsneState:

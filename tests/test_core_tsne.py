@@ -1,4 +1,5 @@
 """Core BH t-SNE correctness: every step validated against the exact oracle."""
+import functools
 import re
 
 import jax
@@ -379,6 +380,149 @@ class TestAttractive:
         f_edges, kl_edges = attractive_forces_edges(jnp.asarray(y), src, dst, w)
         np.testing.assert_allclose(np.asarray(f_edges), np.asarray(f_ell), rtol=1e-4, atol=1e-7)
         np.testing.assert_allclose(float(kl_edges), float(kl_ell), rtol=1e-4)
+
+
+# ---------------------------------------------------------- degree buckets --
+def _skewed_ell(seed=5):
+    """A graph whose degrees spread widely: a tight cluster beside a wide one."""
+    rng = np.random.default_rng(seed)
+    x = np.concatenate([rng.normal(size=(260, 8)) * 0.1,
+                        rng.normal(size=(240, 8)) * 3.0]).astype(np.float32)
+    idx, d2 = knn(jnp.asarray(x), 12)
+    cond_p, _ = bsp_search(d2, 5.0)
+    sym_cols, sym_vals = similarity.symmetrize_ell(idx, cond_p)
+    return x, idx, cond_p, sym_cols, sym_vals.astype(np.float32)
+
+
+def _layout_leaves(buckets):
+    return [np.asarray(a) for a in jax.tree.leaves(buckets)]
+
+
+class TestDegreeBuckets:
+    def test_bucketed_matches_ell_and_dense_oracle(self):
+        from repro.core.attractive import attractive_forces_bucketed
+
+        _, idx, cond_p, cols, vals = _skewed_ell()
+        buckets = similarity.degree_buckets(cols, vals, block=64)
+        assert len(buckets.cols) >= 3
+        real = np.count_nonzero(cols != np.arange(len(cols))[:, None])
+        assert real / buckets.slots > 1.5 * real / cols.size
+        y = jnp.asarray(np.random.default_rng(7).normal(size=(500, 2)),
+                        jnp.float32)
+        f_b, kl_b = attractive_forces_bucketed(
+            y, jax.tree.map(jnp.asarray, buckets))
+        f_e, kl_e = attractive_forces_ell(y, jnp.asarray(cols),
+                                          jnp.asarray(vals))
+        p_dense = similarity.dense_p_matrix(idx, cond_p)
+        f_x, kl_x = exact.exact_attraction(y, jnp.asarray(p_dense, jnp.float32))
+        for f, kl in ((f_e, kl_e), (f_x, kl_x)):
+            np.testing.assert_allclose(np.asarray(f_b), np.asarray(f),
+                                       rtol=1e-5, atol=1e-8)
+            np.testing.assert_allclose(float(kl_b), float(kl), rtol=1e-5)
+
+    def test_bucketed_forces_are_the_whole_ell_loops_to_the_bit(self):
+        """Each row is still summed over the ELL's whole width, so the forces
+        round as the whole-ELL loop's do (on this backend, whose row sums do
+        not depend on the block's row count)."""
+        from repro.core.attractive import (
+            attractive_forces_bucketed, attractive_forces_ell_blocked)
+
+        _, _, _, cols, vals = _skewed_ell(seed=11)
+        y = jnp.asarray(np.random.default_rng(13).normal(size=(500, 2)),
+                        jnp.float32)
+        buckets = similarity.degree_buckets(cols, vals, block=64)
+        f_b, _ = jax.jit(attractive_forces_bucketed)(
+            y, jax.tree.map(jnp.asarray, buckets))
+        f_e, _ = jax.jit(attractive_forces_ell_blocked, static_argnums=3)(
+            y, jnp.asarray(cols), jnp.asarray(vals), 64)
+        np.testing.assert_array_equal(np.asarray(f_b), np.asarray(f_e))
+
+    def test_uniform_degrees_give_one_bucket_equal_to_the_ell(self):
+        n, k = 300, 6
+        ring = (np.arange(n)[:, None] + np.arange(1, k + 1)) % n
+        cols, vals = similarity.symmetrize_ell(ring, np.full((n, k), 1.0 / k))
+        assert (cols != np.arange(n)[:, None]).all()       # every row full
+        b = similarity.degree_buckets(cols, vals, block=512)
+        assert len(b.cols) == 1
+        assert b.cols[0].shape == (1, n + (-n) % 8, 2 * k)
+        np.testing.assert_array_equal(b.cols[0].reshape(-1, 2 * k)[:n], cols)
+        np.testing.assert_array_equal(b.vals[0].reshape(-1, 2 * k)[:n], vals)
+        np.testing.assert_array_equal(b.rows[0].reshape(-1)[:n], np.arange(n))
+        np.testing.assert_array_equal(b.inv, np.arange(n))
+
+    @pytest.mark.parametrize("chunk", [40, 100])
+    def test_turn_rows_respect_chunk_size(self, chunk):
+        from repro.core.tsne import preprocess
+
+        x, *_ = _skewed_ell()
+        cfg = TsneConfig(perplexity=5.0, n_neighbors=12, chunk_size=chunk)
+        graph, timings = preprocess(jnp.asarray(x), cfg)
+        w_max = graph.p_cols.shape[1]
+        block = cfg.resolve_attractive_block()
+        for rows, cols in zip(graph.buckets.rows, graph.buckets.cols):
+            t, r, w = cols.shape
+            assert rows.shape == (t, r)
+            assert r <= chunk and r * w <= block * w_max
+        assert timings["attractive_buckets"] == len(graph.buckets.cols) > 1
+        assert timings["attractive_slots"] == graph.buckets.slots
+        assert 0.5 < timings["attractive_fill"] <= 1.0
+
+    def test_layout_survives_the_chunked_symmetrize(self):
+        from repro.core.tsne import attractive_layout, preprocess
+
+        x, idx, cond_p, cols, _ = _skewed_ell()
+        cfg = TsneConfig(perplexity=5.0, n_neighbors=12, chunk_size=64)
+        graph, _ = preprocess(jnp.asarray(x), cfg)
+        sym_cols, sym_vals = similarity.symmetrize_ell(idx, cond_p)
+        sym_vals = sym_vals / sym_vals.sum()
+        ref = attractive_layout(sym_cols, sym_vals.astype(np.float32), cfg)
+        got, want = _layout_leaves(graph.buckets), _layout_leaves(ref)
+        assert len(got) == len(want)
+        for a, b in zip(got, want):
+            assert a.dtype == b.dtype and a.shape == b.shape
+            np.testing.assert_array_equal(a, b)
+
+    def test_layout_survives_save_and_load(self, tmp_path):
+        from repro.api import TSNE
+
+        x, *_ = _skewed_ell()
+        est = TSNE(perplexity=5.0, n_iter=10, random_state=0).fit(x)
+        est.save(tmp_path / "model.npz")
+        loaded = TSNE.load(tmp_path / "model.npz")
+        got = _layout_leaves(loaded.neighbor_graph_.buckets)
+        want = _layout_leaves(est.neighbor_graph_.buckets)
+        assert len(got) == len(want) > 3
+        for a, b in zip(got, want):
+            assert a.dtype == b.dtype and a.shape == b.shape
+            np.testing.assert_array_equal(a, b)
+
+    @pytest.mark.parametrize("method", ["barnes_hut", "fft"])
+    def test_step_runs_the_buckets(self, method):
+        """With buckets the 'blocked' step reads them, not the padded ELL,
+        and its gradient is the whole-ELL loop's."""
+        import dataclasses
+
+        from repro.api.backends import make_backend
+        from repro.core.tsne import init_state, preprocess, tsne_step
+
+        x, *_ = _skewed_ell()
+        cfg = TsneConfig(perplexity=5.0, n_neighbors=12, method=method,
+                         fft_n_boxes=8)
+        graph, _ = preprocess(jnp.asarray(x), cfg)
+        step = functools.partial(
+            tsne_step, init_state(500, cfg), exaggeration=jnp.float32(12.0),
+            momentum=jnp.float32(0.5), backend=make_backend(method, cfg, 500),
+            lr=10.0, min_gain=0.01)
+        _, with_buckets = step(graph=graph)
+        _, whole_ell = step(graph=dataclasses.replace(graph, buckets=None))
+        # a graph whose ELL is all padding: the forces come from the buckets
+        _, buckets_only = step(graph=dataclasses.replace(
+            graph, p_vals=jnp.zeros_like(graph.p_vals)))
+        for other in (whole_ell, buckets_only):
+            np.testing.assert_allclose(float(with_buckets.kl),
+                                       float(other.kl), rtol=1e-5)
+            np.testing.assert_allclose(float(with_buckets.grad_norm),
+                                       float(other.grad_norm), rtol=1e-5)
 
 
 # --------------------------------------------------------------------- bsp --

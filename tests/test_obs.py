@@ -229,7 +229,8 @@ class TestTracedFit:
         assert set(est.timings_) == {
             "knn", "bsp", "symmetrize", "gradient_descent",
             "neighbor_method", "n_neighbors", "bsp_impl", "chunk_size",
-            "knn_mean_d2", "max_traversal", "mean_traversal", "fft_span"}
+            "knn_mean_d2", "max_traversal", "mean_traversal", "fft_span",
+            "attractive_fill", "attractive_slots", "attractive_buckets"}
         d = est.tracer_.durations()
         assert est.timings_["gradient_descent"] == pytest.approx(
             d["early_exaggeration"], rel=0.05)

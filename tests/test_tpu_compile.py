@@ -18,7 +18,14 @@ from repro.kernels import ops
 
 N = 70_000          # mnist: 70,000 x 784
 K = 90              # 3 * perplexity 30
-W = 397             # symmetric ELL width of the mnist cell's exact KNN graph
+W = 409             # symmetric ELL width of the mnist cell's exact K = 91 graph
+# (turns, rows a turn, width) of each degree bucket of the cells' graphs
+# (similarity.degree_buckets at the default 512-row block)
+MNIST_BUCKETS = [(1, 96, 409), (2, 592, 328), (5, 680, 264), (7, 936, 216),
+                 (9, 1128, 176), (10, 1312, 144), (14, 1736, 120),
+                 (6, 1896, 96)]
+DIGITS_N, DIGITS_W = 1797, 189
+DIGITS_BUCKETS = [(1, 176, 189), (1, 248, 152), (1, 568, 128), (1, 808, 104)]
 DIM = 784
 BLOCK_Q, BLOCK_DB = 512, 2048    # the exact KNN's distance tile
 # lattice nodes per dimension at the default 48 boxes and at the cap
@@ -44,6 +51,47 @@ def spec(topo):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     return make
+
+
+def _buckets(s, n, shapes):
+    """Shapes of a graph's degree buckets."""
+    from repro.core.similarity import DegreeBuckets
+
+    i32 = jnp.int32
+    return DegreeBuckets(
+        rows=tuple(s((t, r), i32) for t, r, _ in shapes),
+        cols=tuple(s((t, r, w), i32) for t, r, w in shapes),
+        vals=tuple(s((t, r, w)) for t, r, w in shapes),
+        inv=s((n,), i32))
+
+
+def _gathers_outside_loops(text):
+    """Output sizes of the compiled module's gathers that no while body
+    reaches."""
+    import math
+    import re
+
+    lines, calls, bodies, comp = {}, {}, set(), None
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.-]+) .*\{$", line)
+        if head:
+            comp = head.group(1)
+            lines[comp], calls[comp] = [], set()
+        elif comp is not None:
+            lines[comp].append(line)
+            calls[comp].update(re.findall(
+                r"(?:calls|to_apply|condition|body)=%([\w.-]+)", line))
+            bodies.update(re.findall(r"body=%([\w.-]+)", line))
+    inside, todo = set(), list(bodies)
+    while todo:
+        c = todo.pop()
+        if c not in inside:
+            inside.add(c)
+            todo.extend(calls.get(c, ()))
+    return [math.prod(int(d) for d in m.group(1).split(",") if d)
+            for comp, body in lines.items() if comp not in inside
+            for line in body
+            if (m := re.search(r"= \w+\[([\d,]*)\]\S* gather\(", line))]
 
 
 def _cases(s):
@@ -93,12 +141,13 @@ def test_descent_step_layers_survive_the_v5e_compile(spec):
     from repro.core import scopes
     from repro.core.tsne import NeighborGraph, TsneConfig, TsneState, tsne_step
 
-    n, w, i32 = 1797, 150, jnp.int32          # the digits cell
+    n, w, i32 = DIGITS_N, DIGITS_W, jnp.int32          # the digits cell
     state = TsneState(y=spec((n, 2)), velocity=spec((n, 2)),
                       gains=spec((n, 2)), iteration=spec((), i32))
     graph = NeighborGraph(
         p_cols=spec((n, w), i32), p_vals=spec((n, w)), edge_src=spec((1,), i32),
-        edge_dst=spec((1,), i32), edge_w=spec((1,)), p_logp=spec(()), n=n)
+        edge_dst=spec((1,), i32), edge_w=spec((1,)), p_logp=spec(()), n=n,
+        buckets=_buckets(spec, n, DIGITS_BUCKETS))
     text = tsne_step.lower(
         state, graph, spec(()), spec(()),
         backend=make_backend("barnes_hut", TsneConfig(), n), lr=149.75,
@@ -111,11 +160,27 @@ def test_descent_step_layers_survive_the_v5e_compile(spec):
         if " while(" in line:
             path = re.search(r'op_name="([^"]*)"', line).group(1).split("/")
             loops[line.split()[0]] = [p for p in path if p in scopes.STEP_SCOPES]
-    # the tree build's searchsorted, the walk and the attractive row blocks
-    assert sorted(v[-1] for v in loops.values()) == \
-        [scopes.ATTRACTIVE, scopes.BH_TRAVERSAL, scopes.BH_TREE]
+    # the tree build's searchsorted, the walk, and the attractive buckets'
+    # turns (a bucket of one turn runs as no loop)
+    layers = sorted(v[-1] for v in loops.values())
+    assert [v for v in layers if v != scopes.ATTRACTIVE] == \
+        [scopes.BH_TRAVERSAL, scopes.BH_TREE]
     for name in (scopes.BH_SUMMARIZE, scopes.UPDATE):
         assert f"/{name}/" in entry, name
+
+
+def test_bucketed_attractive_gathers_a_turn_at_a_time(spec):
+    """At the mnist cell's bucket shapes every gather larger than one turn's
+    runs inside a loop: none is left for the v5e compiler to unroll at the
+    size of the whole graph."""
+    from repro.core.attractive import attractive_forces_bucketed
+
+    text = jax.jit(attractive_forces_bucketed).lower(
+        spec((N, 2)), _buckets(spec, N, MNIST_BUCKETS)).compile().as_text()
+    turn = max(r * w for _, r, w in MNIST_BUCKETS)
+    outside = _gathers_outside_loops(text)
+    assert outside and max(outside) <= turn, (outside, turn)
+    assert text.count(" while(") >= sum(t > 1 for t, _, _ in MNIST_BUCKETS)
 
 
 def test_bh_walk_gathers_one_node_row_per_turn(spec):
@@ -156,7 +221,8 @@ def test_fft_step_interpolates_by_matmul(spec):
                       gains=spec((N, 2)), iteration=spec((), i32))
     graph = NeighborGraph(
         p_cols=spec((N, W), i32), p_vals=spec((N, W)), edge_src=spec((1,), i32),
-        edge_dst=spec((1,), i32), edge_w=spec((1,)), p_logp=spec(()), n=N)
+        edge_dst=spec((1,), i32), edge_w=spec((1,)), p_logp=spec(()), n=N,
+        buckets=_buckets(spec, N, MNIST_BUCKETS))
     cfg = TsneConfig(method="fft", fft_n_boxes=50)
     text = tsne_step.lower(
         state, graph, spec(()), spec(()),
