@@ -325,7 +325,7 @@ def distributed_gradient_phase(x, idx, d2, config, devices) -> None:
     from repro.api.reference import reference_embedding
     from repro.core import bsp
     from repro.core.distributed import distributed_bh_gradient
-    from repro.core.tsne import bh_gradient
+    from repro.core.tsne import NeighborGraph, bh_gradient
 
     n = min(GRAD_N, x.shape[0] - x.shape[0] % len(devices))
     cond_p, _ = bsp.binary_search_perplexity_chunked(
@@ -339,10 +339,12 @@ def distributed_gradient_phase(x, idx, d2, config, devices) -> None:
     y = reference_embedding(x[:n])
     kw = dict(theta=0.5, exaggeration=1.0, depth=16)
 
-    one = jax.device_put((y, cols, vals, p_logp), devices[0])
+    graph = NeighborGraph.from_ell(np.asarray(cols), np.asarray(vals),
+                                   p_logp, config)
+    one = jax.device_put((y, graph), devices[0])
     t0 = time.perf_counter()
     g1 = jax.block_until_ready(jax.jit(
-        lambda y, c, v, p: bh_gradient(y, c, v, None, p_logp=p, **kw))(*one))
+        lambda y, g: bh_gradient(y, g, **kw))(*one))
     t1 = time.perf_counter()
     mesh = jax.sharding.Mesh(np.asarray(devices), ("data",))
     rows = NamedSharding(mesh, PartitionSpec("data"))

@@ -27,8 +27,7 @@ import jax.numpy as jnp
 from repro.core import attractive, exact, scopes
 from repro.core.fft_repulsion import fft_repulsion, lattice_extent
 from repro.core.tsne import (
-    DEFAULT_ATTRACTIVE_IMPL, GradResult, NeighborGraph, TsneConfig, bh_gradient,
-    combine_forces,
+    GradResult, NeighborGraph, TsneConfig, bh_gradient, combine_forces,
 )
 
 
@@ -49,32 +48,6 @@ class GradientBackend(Protocol):
 
 
 # --------------------------------------------------------------------------
-# Shared attractive-term dispatch (exaggeration-free; callers scale it)
-# --------------------------------------------------------------------------
-
-def _attractive(y, graph: NeighborGraph, attractive_impl: str,
-                attractive_block: int = 512):
-    with jax.named_scope(scopes.ATTRACTIVE):
-        if attractive_impl == "edges":
-            if not graph.has_edges:
-                raise ValueError(
-                    "attractive_impl='edges' but the NeighborGraph carries no "
-                    "edge list — preprocess with "
-                    "TsneConfig(attractive_impl='edges')"
-                )
-            return attractive.attractive_forces_edges(y, *graph.edges)
-        if graph.p_cols.shape[0] != y.shape[0]:
-            raise ValueError(
-                f"attractive_impl={attractive_impl!r} needs the ELL rows, but "
-                "this NeighborGraph was preprocessed edges-only "
-                "(attractive_impl='edges')"
-            )
-        return attractive.ell_forces(y, graph.p_cols, graph.p_vals,
-                                     attractive_impl, attractive_block,
-                                     graph.buckets)
-
-
-# --------------------------------------------------------------------------
 # First-class backends
 # --------------------------------------------------------------------------
 
@@ -86,11 +59,6 @@ class ExactBackend:
 
     def gradient(self, y, graph: NeighborGraph, exaggeration) -> GradResult:
         n = y.shape[0]
-        if graph.p_cols.shape[0] != n:
-            raise ValueError(
-                "the exact backend needs the ELL rows, but this NeighborGraph "
-                "was preprocessed edges-only (attractive_impl='edges')"
-            )
         rows = jnp.arange(n, dtype=graph.p_cols.dtype)[:, None]
         # densify the ELL rows; padding entries carry val 0 on the diagonal
         p_dense = jnp.zeros((n, n), y.dtype).at[rows, graph.p_cols].add(graph.p_vals)
@@ -109,31 +77,11 @@ class BarnesHutBackend:
     depth: int = 16
     compress_tree: bool = True
     use_pallas: bool = False
-    attractive_impl: str = DEFAULT_ATTRACTIVE_IMPL
-    # row block of the 'blocked' attractive variant — follows
-    # TsneConfig.resolve_attractive_block() so the preprocessing chunk_size
-    # also bounds the gradient-side gather transients
-    attractive_block: int = 512
 
     def gradient(self, y, graph: NeighborGraph, exaggeration) -> GradResult:
-        if self.attractive_impl == "edges" and not graph.has_edges:
-            raise ValueError(
-                "attractive_impl='edges' but the NeighborGraph carries no edge "
-                "list — preprocess with TsneConfig(attractive_impl='edges')"
-            )
-        if self.attractive_impl != "edges" and graph.p_cols.shape[0] != y.shape[0]:
-            raise ValueError(
-                f"attractive_impl={self.attractive_impl!r} needs the ELL rows, "
-                "but this NeighborGraph was preprocessed edges-only"
-            )
-        edges = graph.edges if self.attractive_impl == "edges" else None
         return bh_gradient(
-            y, graph.p_cols, graph.p_vals, edges,
-            self.theta, exaggeration, self.depth, graph.p_logp,
+            y, graph, self.theta, exaggeration, self.depth,
             compress_tree=self.compress_tree, use_pallas=self.use_pallas,
-            attractive_impl=self.attractive_impl,
-            attractive_block=self.attractive_block,
-            buckets=graph.buckets,
         )
 
 
@@ -148,13 +96,12 @@ class FFTBackend:
 
     name: ClassVar[str] = "fft"
     n_boxes: int = 48
-    attractive_impl: str = DEFAULT_ATTRACTIVE_IMPL
     interp_impl: str = "xla"
-    attractive_block: int = 512
 
     def gradient(self, y, graph: NeighborGraph, exaggeration) -> GradResult:
-        f_attr, kl_attr = _attractive(y, graph, self.attractive_impl,
-                                      self.attractive_block)
+        with jax.named_scope(scopes.ATTRACTIVE):
+            f_attr, kl_attr = attractive.attractive_forces_bucketed(
+                y, graph.buckets)
         f_rep_unnorm, z = fft_repulsion(y, n_boxes=self.n_boxes,
                                         interp_impl=self.interp_impl)
         with jax.named_scope(scopes.FFT_SPREAD):
@@ -223,14 +170,10 @@ def _make_barnes_hut(config: TsneConfig, n: int) -> BarnesHutBackend:
         depth=config.resolve_depth(n),
         compress_tree=config.compress_tree,
         use_pallas=config.use_pallas,
-        attractive_impl=config.attractive_impl,
-        attractive_block=config.resolve_attractive_block(),
     )
 
 
 @register_backend("fft")
 def _make_fft(config: TsneConfig, n: int) -> FFTBackend:
     return FFTBackend(n_boxes=config.fft_n_boxes,
-                      attractive_impl=config.attractive_impl,
-                      interp_impl=config.resolve_fft_interp_impl(),
-                      attractive_block=config.resolve_attractive_block())
+                      interp_impl=config.resolve_fft_interp_impl())

@@ -17,7 +17,7 @@ import numpy as np
 from repro import obs
 from repro.core.tsne import (
     IterationStats, NeighborGraph, ObserverFn, TsneConfig, TsneResult,
-    attractive_layout, run_tsne,
+    run_tsne,
 )
 from repro.api.backends import GradientBackend, make_backend
 
@@ -343,11 +343,7 @@ class TSNE:
             arrays.update(
                 graph_p_cols=np.asarray(g.p_cols, np.int32),
                 graph_p_vals=np.asarray(g.p_vals, np.float32),
-                graph_edge_src=np.asarray(g.edge_src, np.int32),
-                graph_edge_dst=np.asarray(g.edge_dst, np.int32),
-                graph_edge_w=np.asarray(g.edge_w, np.float32),
                 graph_p_logp=np.float64(g.p_logp),
-                graph_has_edges=np.bool_(g.has_edges),
             )
         np.savez_compressed(path, **arrays)
 
@@ -367,6 +363,10 @@ class TSNE:
                 f"(expected {cls._SAVE_SCHEMA})"
             )
         params = json.loads(str(z["params_json"]))
+        # a file may name backend options that this version no longer has
+        fields = {f.name for f in dataclasses.fields(TsneConfig)}
+        params["backend_options"] = {
+            k: v for k, v in params["backend_options"].items() if k in fields}
         est = cls(**params)
         est.embedding_ = np.asarray(z["embedding"])
         est._x_fit = np.asarray(z["x_fit"])
@@ -378,18 +378,13 @@ class TSNE:
         est.n_features_in_ = est._x_fit.shape[1]
         est.timings_ = None         # loaded, not fitted here: no phase ran
         est._query_index = None
-        if "graph_p_cols" in z.files:
-            n = est._x_fit.shape[0]
-            p_cols, p_vals = z["graph_p_cols"], z["graph_p_vals"]
-            est.neighbor_graph_ = NeighborGraph(
-                p_cols=p_cols, p_vals=p_vals,
-                edge_src=z["graph_edge_src"], edge_dst=z["graph_edge_dst"],
-                edge_w=z["graph_edge_w"], p_logp=float(z["graph_p_logp"]),
-                n=n, has_edges=bool(z["graph_has_edges"]),
-                # the attractive loop's layout is rebuilt, not stored
-                buckets=attractive_layout(p_cols, p_vals,
-                                          est._build_config(n)),
-            )
+        n = est._x_fit.shape[0]
+        # files from edges-only fits hold [1, 1] dummy planes: no ELL to load
+        if "graph_p_cols" in z.files and len(z["graph_p_cols"]) == n:
+            # the attractive loop's degree buckets are rebuilt, not stored
+            est.neighbor_graph_ = NeighborGraph.from_ell(
+                z["graph_p_cols"], z["graph_p_vals"],
+                float(z["graph_p_logp"]), est._build_config(n))
         else:
             est.neighbor_graph_ = None
         return est

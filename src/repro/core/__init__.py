@@ -3,13 +3,12 @@ from repro.core.morton import morton_encode, span_radius, DEFAULT_DEPTH
 from repro.core.quadtree import build_quadtree, sort_points_by_code, LinearQuadtree
 from repro.core.summarize import summarize, TreeSummary
 from repro.core.repulsive import bh_repulsion_sorted, RepulsionResult
-from repro.core.attractive import attractive_forces_ell, attractive_forces_edges
+from repro.core.attractive import attractive_forces_ell
 from repro.core.bsp import binary_search_perplexity, perplexity_of
 from repro.core.knn import knn
 from repro.core.tsne import (
-    DEFAULT_ATTRACTIVE_IMPL, GradResult, IterationStats, NeighborGraph,
-    TsneConfig, TsneResult, bh_gradient, init_state, preprocess, run_tsne,
-    tsne_step,
+    GradResult, IterationStats, NeighborGraph, TsneConfig, TsneResult,
+    bh_gradient, init_state, preprocess, run_tsne, tsne_step,
 )
 
 __all__ = [
@@ -17,10 +16,10 @@ __all__ = [
     "build_quadtree", "sort_points_by_code", "LinearQuadtree",
     "summarize", "TreeSummary",
     "bh_repulsion_sorted", "RepulsionResult",
-    "attractive_forces_ell", "attractive_forces_edges",
+    "attractive_forces_ell",
     "binary_search_perplexity", "perplexity_of",
     "knn",
-    "DEFAULT_ATTRACTIVE_IMPL", "GradResult", "IterationStats", "NeighborGraph",
+    "GradResult", "IterationStats", "NeighborGraph",
     "TsneConfig", "TsneResult", "run_tsne", "bh_gradient", "tsne_step",
     "preprocess", "init_state",
 ]

@@ -13,24 +13,24 @@ On the chip the cost of the ELL forms is one scalar gather issue per
 column (two per entry, x and y), whatever the bytes: the layer runs far
 below the HBM roofline, so what it costs is the count of gathered columns.
 ``symmetrize_ell`` pads every row to the graph's largest degree W, which is
-three times the mean on MNIST.  ``attractive_forces_bucketed`` (the default
-'blocked' path whenever the graph carries degree buckets) gathers each row
-only up to its bucket's width; ``attractive_forces_ell_blocked``, a loop over
-512-row blocks of the whole width, is its oracle and the path of a graph
-without buckets.  A loop bounds each gather's size: the v5e compiler
-unrolls a single very large gather into code that grows with it.
+three times the mean on MNIST.
 
-Two equivalent formulations are provided:
+``attractive_forces_bucketed`` is the path every gradient backend runs: a
+loop over the ELL's degree buckets (``similarity.degree_buckets``), each
+row gathering only up to its bucket's width.  A loop bounds each gather's
+size: the v5e compiler unrolls a single very large gather into code that
+grows with it.  The other forms are its oracles:
 
-``attractive_forces_ell``   — Algorithm 2 verbatim over a symmetric ELL matrix
-                              (rows hold the full symmetric p_ij values).
-``attractive_forces_edges`` — scatter/segment-sum over the 2NK directed-edge
-                              list; exactly symmetric by construction and
-                              fully jittable without host preprocessing (used
-                              by the distributed path).
+``attractive_forces_ell_blocked`` — a loop over 512-row blocks of the whole
+                                    width W; the bucketed loop's forces to
+                                    the bit.
+``attractive_forces_ell``         — Algorithm 2 verbatim, one gather over
+                                    the whole ELL; the reference of the
+                                    Pallas kernel (``kernels/ops.py``).
 
-Both also return sum_ij p_ij * log(1 + d_ij^2), the attractive half of the
-KL-divergence estimate.
+Each also returns sum_ij p_ij * log(1 + d_ij^2), the attractive half of the
+KL-divergence estimate.  ``attractive_forces_frozen`` is ``transform``'s
+term against fixed neighbours.
 """
 from __future__ import annotations
 
@@ -54,27 +54,6 @@ def attractive_forces_ell(y: jax.Array, cols: jax.Array, vals: jax.Array):
     force = jnp.sum(pq[..., None] * diff, axis=1)  # [N, 2]
     kl_attr = jnp.sum(vals * jnp.log1p(d2))
     return force, kl_attr
-
-
-def attractive_forces_ell_components(y: jax.Array, cols: jax.Array, vals: jax.Array):
-    """Algorithm 2 in structure-of-arrays form (§Perf hillclimb).
-
-    The [N, W, 2] interleaved layout of ``attractive_forces_ell`` loads x/y
-    components at stride 2, which defeats both AVX and VPU lane vectorization;
-    gathering each coordinate into its own [N, W] plane keeps every op unit
-    stride.  Numerically identical (tested).
-    """
-    yx, yy = y[:, 0], y[:, 1]
-    gx = yx[cols]                                  # [N, W] unit-stride planes
-    gy = yy[cols]
-    dx = yx[:, None] - gx
-    dy = yy[:, None] - gy
-    d2 = dx * dx + dy * dy
-    pq = vals / (1.0 + d2)
-    fx = jnp.sum(pq * dx, axis=1)
-    fy = jnp.sum(pq * dy, axis=1)
-    kl_attr = jnp.sum(vals * jnp.log1p(d2))
-    return jnp.stack([fx, fy], axis=1), kl_attr
 
 
 def _block_terms(yx, yy, x0, y0, cb, vb, width: int | None = None):
@@ -104,8 +83,7 @@ def attractive_forces_ell_blocked(y: jax.Array, cols: jax.Array, vals: jax.Array
 
     Each turn gathers ``block * W`` columns, so the live transients are
     bounded by the block and not by N.  Every row gathers the whole width
-    W, padding included: the oracle of :func:`attractive_forces_bucketed`,
-    and the path of a graph built without degree buckets.
+    W, padding included: the oracle of :func:`attractive_forces_bucketed`.
     """
     n, w = cols.shape
     pad = (-n) % block
@@ -157,37 +135,6 @@ def attractive_forces_bucketed(y: jax.Array, buckets):
     return force[buckets.inv], kl
 
 
-# Single dispatch table for the ELL-layout variants — shared by bh_gradient
-# and the api backends so a new implementation is registered exactly once.
-ELL_IMPLS = {
-    "ell": attractive_forces_ell,
-    "components": attractive_forces_ell_components,
-    "blocked": attractive_forces_ell_blocked,
-}
-
-
-def ell_impl(name: str):
-    """Look up an ELL attractive kernel by name ('edges' is not an ELL impl)."""
-    try:
-        return ELL_IMPLS[name]
-    except KeyError:
-        raise ValueError(
-            f"unknown attractive_impl {name!r}; ELL variants: "
-            f"{', '.join(sorted(ELL_IMPLS))} (or 'edges' with an edge list)"
-        ) from None
-
-
-def ell_forces(y: jax.Array, cols: jax.Array, vals: jax.Array, impl: str,
-               block: int = 512, buckets=None):
-    """The ELL attractive term by ``impl`` name; 'blocked' runs over the
-    graph's degree buckets when it carries them."""
-    if impl == "blocked" and buckets is not None:
-        return attractive_forces_bucketed(y, buckets)
-    if impl == "blocked":
-        return attractive_forces_ell_blocked(y, cols, vals, block=block)
-    return ell_impl(impl)(y, cols, vals)
-
-
 def attractive_forces_frozen(y: jax.Array, nbr_y: jax.Array, p: jax.Array):
     """Attractive force of free points against *frozen* neighbor coordinates.
 
@@ -207,25 +154,3 @@ def attractive_forces_frozen(y: jax.Array, nbr_y: jax.Array, p: jax.Array):
     kl_attr = jnp.sum(p * jnp.log1p(d2), axis=1)
     return force, kl_attr
 
-
-def attractive_forces_edges(y: jax.Array, src: jax.Array, dst: jax.Array, w: jax.Array):
-    """Symmetric attractive force from the directed edge list.
-
-    Each directed KNN edge (i -> j, w = p_{j|i} / 2N) contributes
-    f = w * (1+d^2)^-1 (y_i - y_j) to F_i and -f to F_j; summing over all NK
-    directed edges yields exactly  sum_j p_ij (1+d^2)^-1 (y_i - y_j)  with
-    p_ij = (p_{j|i} + p_{i|j}) / 2N.  Scatter-add = segment_sum (TPU native).
-    """
-    n = y.shape[0]
-    ys, yd = y[src], y[dst]
-    diff = ys - yd
-    d2 = jnp.sum(diff * diff, axis=-1)
-    pq = w / (1.0 + d2)
-    f = pq[:, None] * diff
-    force = jnp.zeros_like(y)
-    force = force.at[src].add(f)
-    force = force.at[dst].add(-f)
-    # each ordered pair (i,j) and (j,i) shares d^2: the directed edge carries
-    # its w to both, hence the factor 2.
-    kl_attr = 2.0 * jnp.sum(w * jnp.log1p(d2))
-    return force, kl_attr

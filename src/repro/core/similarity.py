@@ -1,16 +1,12 @@
 """Sparse input-similarity construction (paper §2.2.1).
 
 Produces the symmetric p_ij = (p_{j|i} + p_{i|j}) / 2N over the union of the
-directed KNN neighborhoods in two interchangeable layouts:
+directed KNN neighborhoods:
 
 * ``symmetrize_ell`` — host-side (numpy) construction of a regular ELL
   [N, W] matrix, W = max symmetric row degree (<= K + max indegree).  Runs
   once before gradient descent, so host preprocessing is fine; the GD loop
-  then uses paper-Algorithm-2 verbatim (attractive_forces_ell).
-* ``edge_list`` — jit-safe directed edge list of N*K edges; each edge is
-  applied to both endpoints by attractive_forces_edges, so the symmetric
-  sum over ordered pairs is recovered without materializing it.  Used by
-  the fully jitted / distributed path; numerically identical forces.
+  then runs paper Algorithm 2 over its rows (``core/attractive.py``).
 
 ``degree_buckets`` cuts the ELL's rows into a few buckets by degree, so the
 attractive loop gathers only up to each row's own bucket's width instead of
@@ -23,19 +19,6 @@ import math
 
 import jax
 import numpy as np
-import jax.numpy as jnp
-
-
-def edge_list(cols, cond_p, n: int | None = None):
-    """Directed KNN edges: (src [NK], dst [NK], w [NK] = p_{dst|src} / 2N)."""
-    cols = jnp.asarray(cols)
-    cond_p = jnp.asarray(cond_p)
-    nn, k = cols.shape
-    n = n or nn
-    src = jnp.repeat(jnp.arange(nn, dtype=jnp.int32), k)
-    dst = cols.reshape(-1).astype(jnp.int32)
-    w = cond_p.reshape(-1) / (2.0 * n)
-    return src, dst, w
 
 
 def symmetrize_ell(cols, cond_p):

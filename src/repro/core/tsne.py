@@ -32,13 +32,6 @@ from repro.core.repulsive import bh_repulsion_sorted
 # ``recompiles.tsne_step`` instead of being invisible.
 TSNE_STEP_RETRACES = obs.RecompileProbe("tsne_step")
 
-# Single source of truth for the attractive-kernel variant.  'blocked' loops
-# over row blocks, so each gather stays a turn's size: over the graph's
-# degree buckets when it carries them (each row gathers only up to its
-# bucket's width), else over 512-row blocks of the whole ELL.  TsneConfig,
-# bh_gradient and the backends all default to this constant.
-DEFAULT_ATTRACTIVE_IMPL = "blocked"
-
 # Hard cap on the resolved neighbor width K.  The ELL layouts and the Pallas
 # tile budgets ([256, K] blocks resident in ~16 MB VMEM) are sized for this
 # envelope, and `repro.analysis` certifies the kernel contracts exactly at
@@ -86,9 +79,6 @@ class TsneConfig:
     # FFT-repulsion spread/gather implementation, same semantics
     # (core/fft_repulsion.py dispatch, used by the 'fft' backend)
     fft_interp_impl: str = "auto"
-    # 'blocked' (row-block loop, over degree buckets — default) | 'ell'
-    # (plain vectorized) | 'components' (SoA planes) | 'edges' (scatter)
-    attractive_impl: str = DEFAULT_ATTRACTIVE_IMPL
     compress_tree: bool = True            # False = daal4py-like uncompressed tree
     method: str = "barnes_hut"            # registered gradient backend name
     fft_n_boxes: int = 48                 # grid boxes/dim for the 'fft' backend
@@ -131,9 +121,10 @@ class TsneConfig:
         return max(1, min(int(self.chunk_size), n))
 
     def resolve_attractive_block(self) -> int:
-        """Gradient-side attractive row block: never exceeds the configured
-        preprocessing chunk, so one knob bounds live transients end-to-end
-        (512 rows of the whole ELL width a turn by default)."""
+        """The attractive loop's row block: a degree bucket's turn gathers
+        about this many rows of the whole ELL width (512 by default).  It
+        never exceeds the configured preprocessing chunk, so one knob
+        bounds live transients end-to-end."""
         if self.chunk_size is not None:
             return max(1, min(512, int(self.chunk_size)))
         return 512
@@ -179,24 +170,28 @@ class NeighborGraph:
     """Sparse symmetric input-similarity graph produced by :func:`preprocess`.
 
     A JAX pytree: flows straight through ``jax.jit`` as one operand, so any
-    backend can pick whichever layout it needs (ELL rows or the directed edge
-    list) inside a jitted step.
+    backend reads the layout it needs (the ELL rows or their degree
+    buckets) inside a jitted step.
     """
     p_cols: jax.Array       # [N, W] int32 ELL neighbor indices (pad: row idx)
     p_vals: jax.Array       # [N, W] symmetric p_ij, sums to 1 (pad: 0)
-    edge_src: jax.Array     # [NK] directed KNN edges ([1] dummy when unused)
-    edge_dst: jax.Array
-    edge_w: jax.Array       # p_{dst|src} / 2N
     p_logp: jax.Array       # exact sum_ij p_ij log p_ij (KL constant)
+    # the ELL's rows cut by degree for the attractive loop (from_ell)
+    buckets: similarity.DegreeBuckets
     n: int = dataclasses.field(metadata=dict(static=True), default=0)
-    has_edges: bool = dataclasses.field(metadata=dict(static=True), default=False)
-    # the ELL's rows cut by degree for the 'blocked' attractive loop
-    # (attractive_layout); None: that loop runs over the whole ELL
-    buckets: similarity.DegreeBuckets | None = None
 
-    @property
-    def edges(self) -> tuple[jax.Array, jax.Array, jax.Array] | None:
-        return (self.edge_src, self.edge_dst, self.edge_w) if self.has_edges else None
+    @classmethod
+    def from_ell(cls, p_cols, p_vals, p_logp, config: TsneConfig):
+        """The graph of a host [N, W] ELL, on the device, with the degree
+        buckets the attractive loop runs over: the rows cut by degree
+        (``similarity.degree_buckets``), each turn within the configured
+        row block's indices and the preprocessing chunk's rows."""
+        buckets = similarity.degree_buckets(
+            p_cols, p_vals, block=config.resolve_attractive_block(),
+            max_rows=config.resolve_chunk_size(len(p_cols)))
+        return cls(p_cols=jnp.asarray(p_cols), p_vals=jnp.asarray(p_vals),
+                   p_logp=jnp.asarray(p_logp, config.dtype), n=len(p_cols),
+                   buckets=jax.tree.map(jnp.asarray, buckets))
 
 
 def combine_forces(
@@ -230,18 +225,12 @@ def combine_forces(
 
 def bh_gradient(
     y: jax.Array,
-    p_cols: jax.Array | None,
-    p_vals: jax.Array | None,
-    edges: tuple[jax.Array, jax.Array, jax.Array] | None,
+    graph: NeighborGraph,
     theta: float,
     exaggeration: jax.Array | float,
     depth: int,
-    p_logp: jax.Array | float,
     compress_tree: bool = True,
     use_pallas: bool = False,
-    attractive_impl: str = DEFAULT_ATTRACTIVE_IMPL,
-    attractive_block: int = 512,
-    buckets: similarity.DegreeBuckets | None = None,
 ) -> GradResult:
     # --- quadtree building (step 3) ---
     with jax.named_scope(scopes.BH_TREE):
@@ -266,15 +255,14 @@ def bh_gradient(
         mean_traversal = jnp.mean(rep.steps.astype(y.dtype))
     # --- attractive (step 5) ---
     with jax.named_scope(scopes.ATTRACTIVE):
-        if edges is not None:
-            f_attr, kl_attr = attractive.attractive_forces_edges(y, *edges)
-        elif use_pallas:
+        if use_pallas:
             from repro.kernels.ops import attractive_forces_ell as attr_ell
-            f_attr, kl_attr = attr_ell(y, p_cols, p_vals)
+            f_attr, kl_attr = attr_ell(y, graph.p_cols, graph.p_vals)
         else:
-            f_attr, kl_attr = attractive.ell_forces(
-                y, p_cols, p_vals, attractive_impl, attractive_block, buckets)
-    return combine_forces(f_attr, kl_attr, f_rep, z, exaggeration, p_logp,
+            f_attr, kl_attr = attractive.attractive_forces_bucketed(
+                y, graph.buckets)
+    return combine_forces(f_attr, kl_attr, f_rep, z, exaggeration,
+                          graph.p_logp,
                           max_traversal=max_traversal,
                           mean_traversal=mean_traversal)
 
@@ -422,62 +410,27 @@ def preprocess(
         sp_bsp.sync(cond_p)
 
     n = int(x.shape[0])
-    with timer.span("symmetrize", layout=config.attractive_impl,
-                    chunk_size=chunk) as sp_sym:
-        if config.attractive_impl == "edges":
-            # edge layout: ship only the directed edge list ([N, W] ELL
-            # planes would ride along as dead jit operands of every step).
-            # The exact KL constant comes from an ordered-pair dedup: mutual
-            # KNN edges sum to the symmetric p_ij = (p_{j|i} + p_{i|j}) / 2N.
-            src, dst, w = similarity.edge_list(idx, cond_p)
-            s = np.asarray(src, np.int64)
-            d = np.asarray(dst, np.int64)
-            wv = np.asarray(w, np.float64)
-            key = np.concatenate([s * n + d, d * n + s])
-            val = np.concatenate([wv, wv])
-            _, inv = np.unique(key, return_inverse=True)
-            p = np.bincount(inv, weights=val)
-            p = p / p.sum()
-            p_logp = float((p[p > 0] * np.log(p[p > 0])).sum())
-            has_edges = True
-            p_cols = jnp.zeros((1, 1), jnp.int32)
-            p_vals = jnp.zeros((1, 1), config.dtype)
-            buckets = None
-            fill = {}
+    with timer.span("symmetrize", chunk_size=chunk) as sp_sym:
+        if chunk is not None:
+            sym_cols, sym_vals = similarity.symmetrize_ell_chunked(
+                idx, cond_p, chunk
+            )
         else:
-            if chunk is not None:
-                sym_cols, sym_vals = similarity.symmetrize_ell_chunked(
-                    idx, cond_p, chunk
-                )
-            else:
-                sym_cols, sym_vals = similarity.symmetrize_ell(idx, cond_p)
-            sym_vals = sym_vals / sym_vals.sum()
-            pv = np.asarray(sym_vals)
-            p_logp = float((pv[pv > 0] * np.log(pv[pv > 0])).sum())
-            src = dst = jnp.zeros((1,), jnp.int32)
-            w = jnp.zeros((1,), config.dtype)
-            has_edges = False
-            vals = np.asarray(sym_vals, np.dtype(config.dtype))
-            p_cols = jnp.asarray(sym_cols)
-            p_vals = jnp.asarray(vals)
-            buckets = attractive_layout(sym_cols, vals, config)
-            # the attractive loop's gathered columns, and the share of them
-            # that are real entries (the rest is padding)
-            slots = sym_cols.size if buckets is None else buckets.slots
-            real = np.count_nonzero(sym_cols != np.arange(n)[:, None])
-            fill = dict(attractive_fill=real / slots, attractive_slots=slots,
-                        attractive_buckets=1 if buckets is None
-                        else len(buckets.cols))
-            sp_sym.annotate(**fill)
-        graph = NeighborGraph(
-            p_cols=p_cols, p_vals=p_vals,
-            edge_src=src, edge_dst=dst, edge_w=w,
-            p_logp=jnp.asarray(p_logp, config.dtype),
-            n=n,
-            has_edges=has_edges,
-            buckets=buckets,
-        )
-        sp_sym.sync((graph.p_vals, graph.edge_w, graph.buckets))
+            sym_cols, sym_vals = similarity.symmetrize_ell(idx, cond_p)
+        sym_vals = sym_vals / sym_vals.sum()
+        pv = np.asarray(sym_vals)
+        p_logp = float((pv[pv > 0] * np.log(pv[pv > 0])).sum())
+        graph = NeighborGraph.from_ell(
+            sym_cols, np.asarray(sym_vals, np.dtype(config.dtype)), p_logp,
+            config)
+        # the attractive loop's gathered columns, and the share of them
+        # that are real entries (the rest is padding)
+        real = np.count_nonzero(sym_cols != np.arange(n)[:, None])
+        fill = dict(attractive_fill=real / graph.buckets.slots,
+                    attractive_slots=graph.buckets.slots,
+                    attractive_buckets=len(graph.buckets.cols))
+        sp_sym.annotate(**fill)
+        sp_sym.sync((graph.p_vals, graph.buckets))
     return graph, dict(
         knn=sp_knn.duration_s, bsp=sp_bsp.duration_s,
         symmetrize=sp_sym.duration_s,
@@ -487,18 +440,6 @@ def preprocess(
         knn_mean_d2=float(jnp.mean(d2)),
         **fill,
     )
-
-
-def attractive_layout(p_cols, p_vals, config: TsneConfig):
-    """The degree buckets the 'blocked' attractive loop runs over, on the
-    device (None for the other layouts): the host ELL's rows cut by degree
-    (``similarity.degree_buckets``), each turn within the configured row
-    block's indices and the preprocessing chunk's rows."""
-    if config.attractive_impl != "blocked":
-        return None
-    return jax.tree.map(jnp.asarray, similarity.degree_buckets(
-        p_cols, p_vals, block=config.resolve_attractive_block(),
-        max_rows=config.resolve_chunk_size(len(p_cols))))
 
 
 def init_state(n: int, config: TsneConfig) -> TsneState:

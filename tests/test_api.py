@@ -10,7 +10,6 @@ from repro.api import (
     TsneConfig, available_backends, make_backend, preprocess, register_backend,
     run_tsne, unregister_backend,
 )
-from repro.core.tsne import DEFAULT_ATTRACTIVE_IMPL
 from repro.data.datasets import make_dataset
 
 
@@ -63,15 +62,6 @@ class TestRegistry:
         finally:
             unregister_backend("tagged_exact")
         assert "tagged_exact" not in available_backends()
-
-    def test_attractive_impl_single_source_of_truth(self):
-        # satellite: config and backend defaults must agree
-        assert TsneConfig().attractive_impl == DEFAULT_ATTRACTIVE_IMPL
-        assert BarnesHutBackend().attractive_impl == DEFAULT_ATTRACTIVE_IMPL
-        assert FFTBackend().attractive_impl == DEFAULT_ATTRACTIVE_IMPL
-        cfg = TsneConfig()
-        assert make_backend("barnes_hut", cfg, 100).attractive_impl \
-            == cfg.attractive_impl
 
 
 # -------------------------------------------------- backend equivalence -----

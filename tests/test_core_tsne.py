@@ -1,5 +1,4 @@
 """Core BH t-SNE correctness: every step validated against the exact oracle."""
-import functools
 import re
 
 import jax
@@ -8,14 +7,14 @@ import numpy as np
 import pytest
 
 from repro.core import (
-    DEFAULT_DEPTH, attractive_forces_edges, attractive_forces_ell,
-    bh_gradient, binary_search_perplexity, build_quadtree, knn,
-    morton_encode, perplexity_of, sort_points_by_code, span_radius, summarize,
+    DEFAULT_DEPTH, attractive_forces_ell, bh_gradient,
+    binary_search_perplexity, build_quadtree, knn, morton_encode,
+    perplexity_of, sort_points_by_code, span_radius, summarize,
 )
 from repro.core import exact, similarity
 from repro.core.bsp import binary_search_perplexity as bsp_search
 from repro.core.repulsive import RepulsionResult, bh_repulsion_sorted
-from repro.core.tsne import TsneConfig, run_tsne
+from repro.core.tsne import NeighborGraph, TsneConfig, run_tsne
 
 
 def make_points(n, seed=0, clusters=4, dim=2, std=0.2):
@@ -351,36 +350,6 @@ class TestAttractive:
         np.testing.assert_allclose(np.asarray(f_ell), np.asarray(f_ex), rtol=1e-4, atol=1e-7)
         np.testing.assert_allclose(float(kl_ell), float(kl_ex), rtol=1e-4)
 
-    def test_components_vs_ell(self):
-        from repro.core.attractive import attractive_forces_ell_components
-        n, k = 128, 12
-        x, _ = make_points(n, seed=101, dim=8)
-        idx, d2 = knn(jnp.asarray(x), k)
-        cond_p, _ = bsp_search(d2, 5.0)
-        sym_cols, sym_vals = similarity.symmetrize_ell(idx, cond_p)
-        y, _ = make_points(n, seed=103)
-        f_a, kl_a = attractive_forces_ell(
-            jnp.asarray(y), jnp.asarray(sym_cols), jnp.asarray(sym_vals, jnp.float32))
-        f_b, kl_b = attractive_forces_ell_components(
-            jnp.asarray(y), jnp.asarray(sym_cols), jnp.asarray(sym_vals, jnp.float32))
-        np.testing.assert_allclose(np.asarray(f_b), np.asarray(f_a), rtol=1e-5, atol=1e-8)
-        np.testing.assert_allclose(float(kl_b), float(kl_a), rtol=1e-6)
-
-    def test_edges_vs_ell(self):
-        n, k = 96, 10
-        x, _ = make_points(n, seed=29, dim=6)
-        idx, d2 = knn(jnp.asarray(x), k)
-        cond_p, _ = bsp_search(d2, 4.0)
-        sym_cols, sym_vals = similarity.symmetrize_ell(idx, cond_p)
-        src, dst, w = similarity.edge_list(idx, cond_p)
-        y, _ = make_points(n, seed=31)
-        f_ell, kl_ell = attractive_forces_ell(
-            jnp.asarray(y), jnp.asarray(sym_cols), jnp.asarray(sym_vals, jnp.float32)
-        )
-        f_edges, kl_edges = attractive_forces_edges(jnp.asarray(y), src, dst, w)
-        np.testing.assert_allclose(np.asarray(f_edges), np.asarray(f_ell), rtol=1e-4, atol=1e-7)
-        np.testing.assert_allclose(float(kl_edges), float(kl_ell), rtol=1e-4)
-
 
 # ---------------------------------------------------------- degree buckets --
 def _skewed_ell(seed=5):
@@ -468,14 +437,15 @@ class TestDegreeBuckets:
         assert 0.5 < timings["attractive_fill"] <= 1.0
 
     def test_layout_survives_the_chunked_symmetrize(self):
-        from repro.core.tsne import attractive_layout, preprocess
+        from repro.core.tsne import preprocess
 
         x, idx, cond_p, cols, _ = _skewed_ell()
         cfg = TsneConfig(perplexity=5.0, n_neighbors=12, chunk_size=64)
         graph, _ = preprocess(jnp.asarray(x), cfg)
         sym_cols, sym_vals = similarity.symmetrize_ell(idx, cond_p)
         sym_vals = sym_vals / sym_vals.sum()
-        ref = attractive_layout(sym_cols, sym_vals.astype(np.float32), cfg)
+        ref = NeighborGraph.from_ell(
+            sym_cols, sym_vals.astype(np.float32), 0.0, cfg).buckets
         got, want = _layout_leaves(graph.buckets), _layout_leaves(ref)
         assert len(got) == len(want)
         for a, b in zip(got, want):
@@ -496,28 +466,83 @@ class TestDegreeBuckets:
             assert a.dtype == b.dtype and a.shape == b.shape
             np.testing.assert_array_equal(a, b)
 
+    @pytest.mark.parametrize("edges_only", [False, True])
+    def test_files_with_edge_list_keys_still_load(self, tmp_path, edges_only):
+        """Files saved while the graph could carry a directed edge list hold
+        its keys (``graph_edge_*``, ``graph_has_edges``).  A file from an
+        edges-only fit holds [1, 1] dummy ELL planes: it loads without a
+        graph, which ``transform`` does not need."""
+        import json
+
+        from repro.api import TSNE
+
+        x, idx, cond_p, *_ = _skewed_ell()
+        n, k = idx.shape
+        est = TSNE(perplexity=5.0, n_iter=10, random_state=0).fit(x)
+        est.save(tmp_path / "model.npz")
+        with np.load(tmp_path / "model.npz") as z:
+            arrays = dict(z)
+        if edges_only:
+            params = json.loads(str(arrays["params_json"]))
+            params["backend_options"] = {"attractive_impl": "edges"}
+            arrays.update(
+                params_json=np.array(json.dumps(params)),
+                graph_p_cols=np.zeros((1, 1), np.int32),
+                graph_p_vals=np.zeros((1, 1), np.float32),
+                graph_edge_src=np.repeat(np.arange(n, dtype=np.int32), k),
+                graph_edge_dst=np.asarray(idx, np.int32).reshape(-1),
+                graph_edge_w=np.asarray(cond_p, np.float32).reshape(-1) / (2 * n))
+        else:
+            arrays.update(graph_edge_src=np.zeros(1, np.int32),
+                          graph_edge_dst=np.zeros(1, np.int32),
+                          graph_edge_w=np.zeros(1, np.float32))
+        arrays["graph_has_edges"] = np.bool_(edges_only)
+        np.savez_compressed(tmp_path / "old.npz", **arrays)
+
+        loaded = TSNE.load(tmp_path / "old.npz")
+        if edges_only:
+            assert loaded.neighbor_graph_ is None
+        else:
+            got = _layout_leaves(loaded.neighbor_graph_)
+            want = _layout_leaves(TSNE.load(tmp_path / "model.npz")
+                                  .neighbor_graph_)
+            assert len(got) == len(want) > 5
+            for a, b in zip(got, want):
+                assert a.dtype == b.dtype and a.shape == b.shape
+                np.testing.assert_array_equal(a, b)
+        y = loaded.transform(x[:20])
+        assert y.shape == (20, 2) and np.isfinite(y).all()
+
     @pytest.mark.parametrize("method", ["barnes_hut", "fft"])
-    def test_step_runs_the_buckets(self, method):
-        """With buckets the 'blocked' step reads them, not the padded ELL,
-        and its gradient is the whole-ELL loop's."""
+    def test_step_runs_the_buckets(self, method, monkeypatch):
+        """The step reads the buckets, not the padded ELL, and its gradient
+        is the whole-ELL loop's."""
         import dataclasses
 
         from repro.api.backends import make_backend
+        from repro.core import attractive
         from repro.core.tsne import init_state, preprocess, tsne_step
 
         x, *_ = _skewed_ell()
         cfg = TsneConfig(perplexity=5.0, n_neighbors=12, method=method,
                          fft_n_boxes=8)
         graph, _ = preprocess(jnp.asarray(x), cfg)
-        step = functools.partial(
-            tsne_step, init_state(500, cfg), exaggeration=jnp.float32(12.0),
-            momentum=jnp.float32(0.5), backend=make_backend(method, cfg, 500),
-            lr=10.0, min_gain=0.01)
-        _, with_buckets = step(graph=graph)
-        _, whole_ell = step(graph=dataclasses.replace(graph, buckets=None))
+        args = (init_state(500, cfg), graph, jnp.float32(12.0),
+                jnp.float32(0.5))
+        kw = dict(backend=make_backend(method, cfg, 500), lr=10.0,
+                  min_gain=0.01)
+        _, with_buckets = tsne_step(*args, **kw)
         # a graph whose ELL is all padding: the forces come from the buckets
-        _, buckets_only = step(graph=dataclasses.replace(
-            graph, p_vals=jnp.zeros_like(graph.p_vals)))
+        _, buckets_only = tsne_step(args[0], dataclasses.replace(
+            graph, p_vals=jnp.zeros_like(graph.p_vals)), *args[2:], **kw)
+        # the same step with the whole-ELL loop in the buckets' place (a
+        # new function, so that it is traced anew)
+        monkeypatch.setattr(
+            attractive, "attractive_forces_bucketed",
+            lambda y, _: attractive.attractive_forces_ell_blocked(
+                y, graph.p_cols, graph.p_vals))
+        _, whole_ell = jax.jit(
+            lambda *a: tsne_step.__wrapped__(*a, **kw))(*args)
         for other in (whole_ell, buckets_only):
             np.testing.assert_allclose(float(with_buckets.kl),
                                        float(other.kl), rtol=1e-5)
@@ -571,10 +596,10 @@ class TestGradient:
         sym_cols, sym_vals = similarity.symmetrize_ell(idx, cond_p)
         p_dense = similarity.dense_p_matrix(idx, cond_p)
         y, _ = make_points(n, seed=53)
-        res = bh_gradient(
-            jnp.asarray(y), jnp.asarray(sym_cols), jnp.asarray(sym_vals, jnp.float32),
-            None, theta=0.0, exaggeration=1.0, depth=DEFAULT_DEPTH, p_logp=0.0,
-        )
+        graph = NeighborGraph.from_ell(
+            sym_cols, sym_vals.astype(np.float32), 0.0, TsneConfig())
+        res = bh_gradient(jnp.asarray(y), graph, theta=0.0, exaggeration=1.0,
+                          depth=DEFAULT_DEPTH)
         g_ex = exact.exact_gradient(jnp.asarray(y), jnp.asarray(p_dense, jnp.float32))
         np.testing.assert_allclose(np.asarray(res.grad), np.asarray(g_ex), rtol=5e-3, atol=1e-6)
 
@@ -588,10 +613,10 @@ class TestGradient:
         pv = sym_vals[sym_vals > 0]
         p_logp = float((pv * np.log(pv)).sum())
         y, _ = make_points(n, seed=61)
-        res = bh_gradient(
-            jnp.asarray(y), jnp.asarray(sym_cols), jnp.asarray(sym_vals, jnp.float32),
-            None, theta=0.0, exaggeration=1.0, depth=DEFAULT_DEPTH, p_logp=p_logp,
-        )
+        graph = NeighborGraph.from_ell(
+            sym_cols, sym_vals.astype(np.float32), p_logp, TsneConfig())
+        res = bh_gradient(jnp.asarray(y), graph, theta=0.0, exaggeration=1.0,
+                          depth=DEFAULT_DEPTH)
         kl_ex = exact.exact_kl(jnp.asarray(y), jnp.asarray(p_dense, jnp.float32))
         np.testing.assert_allclose(float(res.kl), float(kl_ex), rtol=1e-3)
 
@@ -619,18 +644,6 @@ class TestEndToEnd:
             for j in range(i + 1, 3):
                 inter.append(np.linalg.norm(cents[i] - cents[j]))
         assert np.mean(intra) < 0.5 * np.mean(inter)
-
-    @pytest.mark.slow
-    def test_edges_impl_close_to_ell(self):
-        n = 300
-        x, _ = make_points(n, seed=71, clusters=3, dim=10)
-        kl = {}
-        for impl in ("ell", "edges"):
-            cfg = TsneConfig(perplexity=10.0, n_iter=150, exaggeration_iters=50,
-                             momentum_switch_iter=50, attractive_impl=impl, seed=2)
-            kl[impl] = run_tsne(x, cfg, kl_every=150).kl_history[-1, 1]
-        # identical forces; KL differs only by the constant-sum-p-log-p estimate
-        assert abs(kl["ell"] - kl["edges"]) < 0.5
 
 
 # --------------------------------------------------- step layers, counters ---
@@ -691,10 +704,11 @@ class TestStepLayers:
         cs, ys, _ = sort_points_by_code(yj, morton_encode(yj, cent, r))
         tree = build_quadtree(cs)
         turns = _numpy_walk(ys, tree, summarize(tree, ys, r), theta)
-        rows = jnp.arange(160, dtype=jnp.int32)[:, None]
-        res = bh_gradient(yj, rows, jnp.zeros((160, 1), jnp.float32), None,
-                          theta=theta, exaggeration=1.0, depth=DEFAULT_DEPTH,
-                          p_logp=0.0)
+        graph = NeighborGraph.from_ell(
+            np.arange(160, dtype=np.int32)[:, None],
+            np.zeros((160, 1), np.float32), 0.0, TsneConfig())
+        res = bh_gradient(yj, graph, theta=theta, exaggeration=1.0,
+                          depth=DEFAULT_DEPTH)
         assert int(res.max_traversal) == turns.max()
         np.testing.assert_allclose(float(res.mean_traversal), turns.mean(),
                                    rtol=1e-6)

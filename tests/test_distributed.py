@@ -35,8 +35,9 @@ def test_distributed_bh_gradient_matches_single_device():
         cond_p, _ = bsp.binary_search_perplexity(d2, 4.0)
         cols, vals = similarity.symmetrize_ell(idx, cond_p)
         y = jnp.asarray(rng.normal(size=(n, 2)).astype(np.float32))
-        ref = tsne.bh_gradient(y, jnp.asarray(cols), jnp.asarray(vals, jnp.float32),
-                               None, theta=0.5, exaggeration=2.0, depth=16, p_logp=0.0)
+        graph = tsne.NeighborGraph.from_ell(
+            cols, vals.astype(np.float32), 0.0, tsne.TsneConfig())
+        ref = tsne.bh_gradient(y, graph, theta=0.5, exaggeration=2.0, depth=16)
         got = distributed_bh_gradient(mesh, y, jnp.asarray(cols),
                                       jnp.asarray(vals, jnp.float32), 0.0,
                                       theta=0.5, exaggeration=2.0)

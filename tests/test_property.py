@@ -1,4 +1,5 @@
 """Hypothesis property tests on the system's invariants."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -8,10 +9,11 @@ from hypothesis import given, settings, strategies as st
 from hypothesis.extra import numpy as hnp
 
 from repro.core import (
-    attractive_forces_edges, binary_search_perplexity, build_quadtree,
-    morton_encode, perplexity_of, sort_points_by_code, span_radius, summarize,
+    binary_search_perplexity, build_quadtree, morton_encode, perplexity_of,
+    sort_points_by_code, span_radius, summarize,
 )
-from repro.core import exact
+from repro.core import exact, similarity
+from repro.core.attractive import attractive_forces_bucketed
 from repro.core.morton import morton_decode_cell
 from repro.core.repulsive import bh_repulsion_sorted
 
@@ -103,13 +105,16 @@ def test_bh_matches_exact_at_theta_zero(y):
     seed=st.integers(0, 2**31 - 1),
 )
 @settings(**SETTINGS)
-def test_attractive_edges_antisymmetry(n, k, seed):
+def test_attractive_bucketed_antisymmetry(n, k, seed):
+    """Over a symmetric P the bucketed loop's forces sum to zero."""
     rng = np.random.default_rng(seed)
     y = jnp.asarray(rng.normal(size=(n, 2)).astype(np.float32))
-    src = jnp.asarray(rng.integers(0, n, size=n * k), jnp.int32)
-    dst = jnp.asarray((rng.integers(1, n, size=n * k) + np.asarray(src)) % n, jnp.int32)
-    w = jnp.asarray(rng.uniform(0, 1, size=n * k).astype(np.float32))
-    f, _ = attractive_forces_edges(y, src, dst, w)
+    cols = (rng.integers(1, n, size=(n, k)) + np.arange(n)[:, None]) % n
+    cols, vals = similarity.symmetrize_ell(
+        cols, rng.uniform(0, 1, size=(n, k)))
+    vals = (vals * 2 * n).astype(np.float32)    # p_{j|i} + p_{i|j}, unscaled
+    buckets = similarity.degree_buckets(cols, vals, block=8)
+    f, _ = attractive_forces_bucketed(y, jax.tree.map(jnp.asarray, buckets))
     np.testing.assert_allclose(np.asarray(f).sum(0), 0.0, atol=1e-3)
 
 

@@ -145,8 +145,7 @@ def test_descent_step_layers_survive_the_v5e_compile(spec):
     state = TsneState(y=spec((n, 2)), velocity=spec((n, 2)),
                       gains=spec((n, 2)), iteration=spec((), i32))
     graph = NeighborGraph(
-        p_cols=spec((n, w), i32), p_vals=spec((n, w)), edge_src=spec((1,), i32),
-        edge_dst=spec((1,), i32), edge_w=spec((1,)), p_logp=spec(()), n=n,
+        p_cols=spec((n, w), i32), p_vals=spec((n, w)), p_logp=spec(()), n=n,
         buckets=_buckets(spec, n, DIGITS_BUCKETS))
     text = tsne_step.lower(
         state, graph, spec(()), spec(()),
@@ -220,8 +219,7 @@ def test_fft_step_interpolates_by_matmul(spec):
     state = TsneState(y=spec((N, 2)), velocity=spec((N, 2)),
                       gains=spec((N, 2)), iteration=spec((), i32))
     graph = NeighborGraph(
-        p_cols=spec((N, W), i32), p_vals=spec((N, W)), edge_src=spec((1,), i32),
-        edge_dst=spec((1,), i32), edge_w=spec((1,)), p_logp=spec(()), n=N,
+        p_cols=spec((N, W), i32), p_vals=spec((N, W)), p_logp=spec(()), n=N,
         buckets=_buckets(spec, N, MNIST_BUCKETS))
     cfg = TsneConfig(method="fft", fft_n_boxes=50)
     text = tsne_step.lower(
